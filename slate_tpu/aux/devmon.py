@@ -57,21 +57,21 @@ _hwm: Dict[Any, int] = {}
 PEAKS_ENV = "SLATE_TPU_PEAKS"
 
 #: built-in peak table: lowercase device-kind substring -> (peak
-#: FLOP/s, peak bytes/s).  Matched by substring so "TPU v4 lite" finds
-#: "tpu v4".  The cpu row is deliberately modest (a few Skylake-class
-#: cores with AVX f64 and dual-channel DRAM) — the roofline verdict
-#: needs the RATIO (the ridge point), not vendor-sheet precision, and
+#: FLOP/s, peak bytes/s), matched by substring in this order, so the
+#: v5e's "TPU v5 lite" finds its own row ahead of the v5p's "tpu v5".
+#: TPU rows are the bf16 peaks and HBM bandwidth of Google Cloud's
+#: per-generation pages ("TPU v4", "TPU v5e", "TPU v5p", "TPU v6e").
+#: The cpu row is deliberately modest (a few Skylake-class cores with
+#: AVX f64 and dual-channel DRAM) — the roofline verdict needs the
+#: RATIO (the ridge point), not vendor-sheet precision, and
 #: SLATE_TPU_PEAKS overrides per deployment.
 DEFAULT_PEAKS: Dict[str, Dict[str, float]] = {
     "cpu": {"flops": 5.0e10, "bytes_per_s": 2.0e10},
     "tpu v4": {"flops": 2.75e14, "bytes_per_s": 1.2e12},
-    "tpu v5": {"flops": 3.9e14, "bytes_per_s": 1.6e12},
-    "tpu v6": {"flops": 9.2e14, "bytes_per_s": 1.6e12},
+    "tpu v5 lite": {"flops": 1.97e14, "bytes_per_s": 8.19e11},
+    "tpu v5": {"flops": 4.59e14, "bytes_per_s": 2.765e12},
+    "tpu v6 lite": {"flops": 9.18e14, "bytes_per_s": 1.64e12},
 }
-
-#: last-resort peaks when no table row matches the device kind: the
-#: cpu row, labeled so reports show the verdict is on defaulted roofs
-FALLBACK_KIND = "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -328,10 +328,10 @@ def peaks_for(kind: Optional[str] = None) -> dict:
     "bytes_per_s", "ridge", "kind", "source"}`` with ridge = peak
     FLOP/s / peak bytes/s (the arithmetic intensity where the roof
     changes slope).  ``SLATE_TPU_PEAKS`` rows win over the built-in
-    table; an unmatched kind falls back to the cpu row with
-    ``source="fallback"`` so reports show the roofs are defaulted."""
+    table; a kind that neither knows raises ``ValueError`` — a roofline
+    against another device's roofs is not a measurement."""
     k = (kind if kind is not None else default_device_kind()).lower()
-    table = dict(DEFAULT_PEAKS)
+    table = DEFAULT_PEAKS
     source = "default"
     env = _env_peaks()
     row = None
@@ -345,11 +345,10 @@ def peaks_for(kind: Optional[str] = None) -> dict:
                 row = vals
                 break
     if row is None:
-        # unmatched kind: fall back to the cpu row — honoring an env
-        # override of it (the operator who replaced the cpu roofs
-        # meant them, fallback path included)
-        row = env.get(FALLBACK_KIND, table[FALLBACK_KIND])
-        source = "fallback"
+        raise ValueError(
+            f"no roofline peaks for device kind {k!r}: add a row to "
+            f"{PEAKS_ENV} or devmon.DEFAULT_PEAKS"
+        )
     return {
         "kind": k,
         "flops": float(row["flops"]),

@@ -97,7 +97,7 @@ def potrf(
         sched, nb_switch, lookahead = resolve_schedule_opts(opts)
         nb_kernel = 512 if n >= 2048 else min(lay.nb, 512)
         if metrics.is_on():
-            route = chol_kernels.resolve_schedule(n, sched)
+            route = chol_kernels.resolve_schedule(n, full.dtype, sched)
             metrics.record_factor_flops(
                 "potrf",
                 chol_kernels.chol_schedule_flops(
@@ -121,7 +121,18 @@ def potrs(
     L: TriangularMatrix, B: Matrix, opts: Optional[Options] = None
 ) -> Matrix:
     """Solve A X = B given the Cholesky factor (reference: src/potrs.cc:
-    two trsm sweeps)."""
+    two trsm sweeps).  Operands on one device solve through
+    ``potrs_from_global`` under the options' schedule."""
+    if not (_is_distributed(L) or _is_distributed(B)) and (
+        L.op == B.op == Op.NoTrans
+    ):
+        Lg = L._with(op=Op.NoTrans).to_global()
+        if L.uplo == Uplo.Upper:
+            Lg = jnp.conj(Lg).T if L.is_complex else Lg.T
+        X = potrs_from_global(
+            Lg, B.to_global(), resolve_schedule_opts(opts)[0]
+        )
+        return B._with(data=tiles_from_global(X, B.layout)).shard()
     if L.uplo == Uplo.Lower:
         Y = blas3.trsm(Side.Left, 1.0, L, B, opts)
         X = blas3.trsm(Side.Left, 1.0, conj_transpose(L), Y, opts)
@@ -131,20 +142,22 @@ def potrs(
     return X
 
 
-def _solve_trsm_route(n: int, schedule: str) -> str:
-    """Schedule routing for the solve-phase trsm pair: explicit
-    ``pallas`` is honored everywhere (interpret mode off-TPU); ``auto``
-    prefers the Pallas pair on accelerators above the same crossover as
-    the factor schedules, the vendor solve otherwise."""
+def _solve_trsm_route(n: int, dtype, schedule: str) -> str:
+    """Schedule routing for the solve-phase trsm pair: ``"pallas"``
+    (the Pallas pair), ``"blocked"`` (its algorithm in plain jnp,
+    ``trsm_blocked``) or ``"vendor"``.  Explicit ``pallas`` is honored
+    everywhere and the other explicit native schedules solve blocked —
+    custom-call-free, so their serve artifacts export on CPU.  ``auto``
+    keeps the vendor solve on CPU and below the factor schedules'
+    crossover; above it the Pallas pair where its kernels compile
+    (``chol_kernels.pallas_compiles``), the blocked loop otherwise."""
     if schedule == "pallas":
         return "pallas"
-    if (
-        schedule == "auto"
-        and jax.default_backend() != "cpu"
-        and n >= chol_kernels.RECURSIVE_MIN_N
-    ):
-        return "pallas"
-    return "vendor"
+    if schedule != "auto":
+        return "blocked"
+    if jax.default_backend() == "cpu" or n < chol_kernels.RECURSIVE_MIN_N:
+        return "vendor"
+    return "pallas" if chol_kernels.pallas_compiles(dtype) else "blocked"
 
 
 def potrs_from_global(
@@ -157,13 +170,18 @@ def potrs_from_global(
     traceable (jit/vmap).  ``schedule="pallas"`` (or ``auto`` on an
     accelerator above the crossover) runs both sweeps through the
     fused Pallas trsm pair (ops/pallas/panel_kernels.py)."""
-    cplx = jnp.iscomplexobj(Lg)
-    if _solve_trsm_route(Lg.shape[0], schedule) == "pallas":
-        from ..ops.pallas import panel_kernels as pk
+    from ..ops.pallas import panel_kernels as pk
 
+    cplx = jnp.iscomplexobj(Lg)
+    route = _solve_trsm_route(Lg.shape[0], Lg.dtype, schedule)
+    if route == "pallas":
         Y = pk.trsm_lower(Lg, Bg)
         U = jnp.conj(Lg).T if cplx else Lg.T
         return pk.trsm_upper(U, Y)
+    if route == "blocked":
+        Y = pk.trsm_blocked(Lg, Bg, lower=True)
+        U = jnp.conj(Lg).T if cplx else Lg.T
+        return pk.trsm_blocked(U, Y, lower=False)
     Y = lax.linalg.triangular_solve(Lg, Bg, left_side=True, lower=True)
     return lax.linalg.triangular_solve(
         Lg, Y, left_side=True, lower=True, transpose_a=True,

@@ -21,6 +21,7 @@ from jax import lax
 
 from ..enums import Diag, MethodLU, Norm, Op, Option, Side, Uplo
 from ..exceptions import slate_assert
+from ..internal.precision import hdot as _dot
 from ..matrix.base import BaseMatrix
 from ..matrix.matrix import Matrix, TriangularMatrix
 from ..options import Options, get_option, resolve_schedule_opts
@@ -206,7 +207,7 @@ def getrf_nopiv(
             # trailing update
             Lpan = jnp.where(row_sel, lax.dynamic_slice(G, (0, k * nb), (n, nb)), 0)
             Urow = jnp.where(col_sel, lax.dynamic_slice(G, (k * nb, 0), (nb, n)), 0)
-            return G - Lpan @ Urow
+            return G - _dot(Lpan, Urow)
 
         return lax.fori_loop(0, n // nb, body, G)
 
@@ -278,10 +279,7 @@ def getrs(
         B2 = pivots.apply(jnp.pad(B2, ((0, pivots.perm.shape[0] - B2.shape[0]), (0, 0))))[
             : B.m
         ]
-    Y = lax.linalg.triangular_solve(
-        G, B2, left_side=True, lower=True, unit_diagonal=True
-    )
-    X = lax.linalg.triangular_solve(G, Y, left_side=True, lower=False)
+    X = getrs_from_global(G, B2, resolve_schedule_opts(opts)[0])
     return B._with(data=tiles_from_global(X.astype(B.dtype), B.layout)).shard()
 
 
@@ -299,19 +297,20 @@ def getrs_from_global(
     steady-state kernel of the serve factor cache's trsm-only
     (``phase="solve"``) bucket family — the factorization's row
     permutation is a host-side gather, so the traced program is pure
-    triangular algebra and exports custom-call-free under the
-    recursive schedule's jax lowering.  Fully traceable (jit/vmap).
-    ``schedule="pallas"`` (or ``auto`` on an accelerator above the
-    crossover) runs both sweeps through the fused Pallas trsm pair —
-    the kernels read only their own triangle, so the packed storage
-    needs no unpacking."""
+    triangular algebra and exports custom-call-free under the native
+    schedules (``chol._solve_trsm_route``).  Fully traceable
+    (jit/vmap).  The Pallas pair and its blocked jnp form read only
+    their own triangle, so the packed storage needs no unpacking."""
+    from ..ops.pallas import panel_kernels as pk
     from .chol import _solve_trsm_route
 
-    if _solve_trsm_route(LUg.shape[0], schedule) == "pallas":
-        from ..ops.pallas import panel_kernels as pk
-
+    route = _solve_trsm_route(LUg.shape[0], LUg.dtype, schedule)
+    if route == "pallas":
         Y = pk.trsm_lower(LUg, Bg, unit=True)
         return pk.trsm_upper(LUg, Y)
+    if route == "blocked":
+        Y = pk.trsm_blocked(LUg, Bg, lower=True, unit=True)
+        return pk.trsm_blocked(LUg, Y, lower=False)
     Y = lax.linalg.triangular_solve(
         LUg, Bg, left_side=True, lower=True, unit_diagonal=True
     )
@@ -453,7 +452,7 @@ def gesv_rbt(
     X = solve(B2)
     # refinement steps (gesv_rbt.cc does IR to recover accuracy)
     for _ in range(2):
-        R = B2 - A2 @ X
+        R = B2 - _dot(A2, X)
         X = X + solve(R)
     Xm = B._with(data=tiles_from_global(X.astype(B.dtype), B.layout)).shard()
     return Xm, LU, Pivots(jnp.arange(0)), info

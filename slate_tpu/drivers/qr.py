@@ -105,7 +105,7 @@ def geqrf(
     sched, nb_switch, _lookahead = resolve_schedule_opts(opts)
     # one resolver decides both the kernel and the accounting route, so
     # the factor.geqrf.* counters always describe the traced program
-    route = _qr_fast.resolve_qr_schedule(mp, npd, sched)
+    route = _qr_fast.resolve_qr_schedule(mp, npd, Gp.dtype, sched)
     if metrics.is_on():
         metrics.record_factor_flops(
             "geqrf",

@@ -275,12 +275,14 @@ class Schedule(_StrParseMixin, enum.Enum):
       (``ops/pallas/panel_kernels.py``): in-register panel LU pivot
       search, fused unblocked Cholesky, compact-WY T assembly,
       triangle-aware syrk diagonal blocks.  Compiled Mosaic on TPU for
-      eligible operands; the identical kernel bodies run in interpret
-      mode (plain XLA lowering) everywhere else, so the family is
-      portable and artifacts stay custom-call-free.
+      eligible (f32, aligned) operands, the jnp twins for the rest; the
+      identical kernel bodies run in interpret mode (plain XLA
+      lowering) off the TPU, so the family is portable and artifacts
+      stay custom-call-free.
     * ``Auto``      — backend dispatch: vendor kernel on CPU (LAPACK is
-      already optimal), pallas above the crossover on accelerators,
-      flat/blocked below it.
+      already optimal); above the crossover on accelerators, pallas
+      where its kernels compile (f32 on the TPU) and recursive for
+      every other dtype; flat/blocked below it.
     """
 
     Auto = "auto"

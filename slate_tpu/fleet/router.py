@@ -119,6 +119,44 @@ class FleetError(SlateError):
     can distinguish fabric trouble from numerical/admission errors."""
 
 
+def local_chip_count() -> int:
+    """TPU chips this host exposes, counted from the device nodes
+    without touching JAX: the router must never claim a chip its
+    workers need (a chip belongs to one process at a time)."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*")
+    )
+
+
+def assign_chips(envs: List[dict], chips: int) -> None:
+    """Give each spawned worker that would hold a chip one of its own,
+    in place, or refuse.  A worker holds a chip on a chip host unless
+    its ``JAX_PLATFORMS`` leaves the TPU out; with several holders each
+    sees one chip through libtpu's per-process visibility variables."""
+    holders = [
+        e for e in envs
+        if chips and (
+            not e.get("JAX_PLATFORMS")
+            or "tpu" in e["JAX_PLATFORMS"].split(",")
+        )
+    ]
+    if len(holders) > chips:
+        raise FleetError(
+            f"{len(holders)} spawned workers would each hold a TPU chip "
+            f"but this host has {chips}: spawn at most {chips}, or run "
+            "the others with JAX_PLATFORMS=cpu"
+        )
+    if len(holders) > 1:
+        for k, e in enumerate(holders):
+            e.update(
+                TPU_VISIBLE_CHIPS=str(k),
+                TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                TPU_PROCESS_BOUNDS="1,1,1",
+            )
+
+
 class HostDead(FleetError):
     """The request's host died (or no live host remains) and the
     re-dispatch budget is exhausted — fail-fast, never a hang."""
@@ -392,9 +430,10 @@ class FleetRouter:
         with self._lock:
             if self._started:
                 return self
+            envs = [self._env_for(i) for i in range(self.spawn)]
+            assign_chips(envs, local_chip_count())
             self._started = True
-        for i in range(self.spawn):
-            env = self._env_for(i)
+        for i, env in enumerate(envs):
             proc, addr = self._spawn_worker(env)
             self._add_host(str(i), addr, proc=proc, spawn_env=env)
         for j, addr in enumerate(self.connect):

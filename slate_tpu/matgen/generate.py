@@ -25,14 +25,16 @@ bit-reproducible for a given seed regardless of tiling or process count.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..exceptions import SlateError
-from ..matrix.base import BaseMatrix
+from ..matrix.base import BaseMatrix, is_distributed
 from ..matrix.matrix import Matrix
 from ..parallel.layout import tiles_from_global
 from . import philox
@@ -346,32 +348,54 @@ def generate_2d(
 
 
 def generate_tiles(
-    kind: str, layout, dtype, seed: int = 42
+    kind: str, layout, dtype, seed: int = 42, sharding=None
 ) -> Optional[jnp.ndarray]:
     """Device-side generation of the (P, Q, mb, nb) storage-order tile
     array for the plain rand kinds: every element draws from the Philox
     counter RNG keyed by its *global* (i, j), so the result is invariant
     to tiling and process count (reference: matgen/random.cc:43-100) —
-    and under a sharded mesh each device generates only its local tiles,
-    with no host round-trip.  Returns None for kinds that need global
+    and with the grid's tile ``sharding`` each device generates only its
+    local tiles, with no host round-trip.  Returns None for kinds that need global
     structure (spectra, special matrices, dominant/zerocol suffixes);
     callers fall back to the host path."""
-    from . import philox
 
     base, dist, sigma_max, dominant, zero_col = parse_kind(kind)
     if base not in _RAND_KINDS or dominant or zero_col is not None:
         return None
-    dtype = jnp.dtype(dtype)
-    gr = jnp.asarray(layout.global_rows_np.astype(np.int64))  # (P, mb)
-    gc = jnp.asarray(layout.global_cols_np.astype(np.int64))  # (Q, nb)
-    i = jnp.broadcast_to(
-        gr[:, None, :, None], (layout.P, layout.Q, layout.mb, layout.nb)
+    return _rand_tiles(
+        jnp.asarray(layout.global_rows_np.astype(np.int64)),  # (P, mb)
+        jnp.asarray(layout.global_cols_np.astype(np.int64)),  # (Q, nb)
+        jnp.asarray(layout.row_mask_np),
+        jnp.asarray(layout.col_mask_np),
+        dist=_RAND_KINDS[base], seed=int(seed), dtype=jnp.dtype(dtype),
+        scale=float(sigma_max), sharding=sharding,
     )
-    j = jnp.broadcast_to(gc[None, :, None, :], i.shape)
-    T = philox.random_jnp(_RAND_KINDS[base], seed, i, j, dtype)
-    if sigma_max != 1.0:
-        T = T * sigma_max
-    return jnp.where(layout.element_mask(), T, 0)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("dist", "seed", "dtype", "scale", "sharding")
+)
+def _rand_tiles(gr, gc, row_mask, col_mask, dist, seed, dtype, scale,
+                sharding):
+    """One compiled program per tile shape: the Philox chain fuses into
+    one elementwise pass (eagerly it is ~30 ops, each compiled per new
+    shape and each materializing a full-size intermediate), partitioned
+    by ``sharding`` when given (a whole n=16384 f64 chain does not fit
+    one v5e)."""
+    shape = (gr.shape[0], gc.shape[0], gr.shape[1], gc.shape[1])
+
+    def place(x):
+        if sharding is None:
+            return x
+        return jax.lax.with_sharding_constraint(x, sharding)
+
+    i = place(jnp.broadcast_to(gr[:, None, :, None], shape))
+    j = place(jnp.broadcast_to(gc[None, :, None, :], shape))
+    T = philox.random_jnp(dist, seed, i, j, dtype)
+    if scale != 1.0:
+        T = T * scale
+    mask = row_mask[:, None, :, None] & col_mask[None, :, None, :]
+    return place(jnp.where(mask, T, 0))
 
 
 def generate_matrix(
@@ -386,8 +410,9 @@ def generate_matrix(
 
     Plain rand kinds generate directly on-device per tile
     (generate_tiles); structured kinds assemble on the host."""
-    lay = A.resolved().layout
-    T = generate_tiles(kind, lay, A.dtype, seed)
+    Ar = A.resolved()
+    sharding = Ar.grid.tile_sharding() if is_distributed(Ar) else None
+    T = generate_tiles(kind, Ar.layout, A.dtype, seed, sharding)
     if T is not None:
         return A._with(data=T).shard(), None
     G, Sigma = generate_2d(
@@ -409,9 +434,11 @@ def generate(
     seed: int = 42,
     cond: Optional[float] = None,
 ) -> Matrix:
-    """Convenience constructor: generate a fresh distributed Matrix."""
-    G, _ = generate_2d(kind, m, n, dtype, seed=seed, cond=cond)
-    return Matrix.from_global(G, mb, nb, grid=grid)
+    """Convenience constructor: generate a fresh distributed Matrix
+    (plain rand kinds on the device, tile by tile; see
+    ``generate_matrix``)."""
+    A = Matrix.zeros(m, n, mb, nb, dtype=dtype, grid=grid)
+    return generate_matrix(kind, A, seed=seed, cond=cond)[0]
 
 
 def cond_matrix(

@@ -277,6 +277,19 @@ def tri_inv_blocked(L: jnp.ndarray, nb: int = 512) -> jnp.ndarray:
 RECURSIVE_MIN_N = 2048
 
 
+def pallas_compiles(dtype) -> bool:
+    """Whether the Pallas panel kernels compile for this dtype here:
+    f32 on the TPU only (Mosaic has no f64/complex vectors).  ``auto``
+    takes the ``pallas`` family above the crossover exactly then; every
+    other dtype takes a single-loop schedule, because the TPU emulates
+    f64 and each op unrolled into a program costs about a second of
+    compile (n=8192 f64 posv for a described v5e: recursive 515 s,
+    ``chol_fori`` 11 s)."""
+    from .pallas.kernels import on_tpu
+
+    return on_tpu() and jnp.dtype(dtype) == jnp.float32
+
+
 def split_point(n: int) -> int:
     """Top-half size of the recursion: ceil(n/2) rounded up to the best
     MXU alignment that still leaves a nonempty trailing half.  For the
@@ -673,18 +686,21 @@ def chol_schedule_flops(
     return {"model": model, "exec": ex, "units": units}
 
 
-def resolve_schedule(n: int, schedule: str = "auto") -> str:
-    """Resolve an ``auto`` schedule request against the backend and
-    size: vendor LAPACK on CPU, the pallas panel-kernel family above
-    the crossover on accelerators, the flat/blocked schedule below it.
-    Explicit ``flat``/``recursive``/``pallas`` are honored on every
-    backend (tests exercise the native schedules on CPU — pallas runs
-    its kernels in interpret mode there)."""
+def resolve_schedule(n: int, dtype, schedule: str = "auto") -> str:
+    """Resolve an ``auto`` schedule request against the backend, size
+    and dtype: vendor LAPACK on CPU; on accelerators the flat/blocked
+    schedule below the crossover and above it ``pallas`` where its
+    kernels compile (``pallas_compiles``), the single-loop
+    ``flat_fori`` otherwise.  Explicit ``flat``/``recursive``/``pallas``
+    are honored on every backend (tests exercise the native schedules
+    on CPU — pallas runs its kernels in interpret mode there)."""
     if schedule in ("flat", "recursive", "pallas"):
         return schedule
     if jax.default_backend() == "cpu":
         return "vendor"
-    return "pallas" if n >= RECURSIVE_MIN_N else "flat"
+    if n < RECURSIVE_MIN_N:
+        return "flat"
+    return "pallas" if pallas_compiles(dtype) else "flat_fori"
 
 
 def cholesky(
@@ -695,16 +711,20 @@ def cholesky(
     lookahead: int = 1,
 ) -> jnp.ndarray:
     """Schedule-dispatched Cholesky: vendor kernel on CPU under ``auto``
-    (LAPACK — already optimal), native blocked (``flat``) or divide &
-    conquer (``recursive``, crossover ``nb_switch``) schedule otherwise.
+    (LAPACK — already optimal), native blocked (``flat``), single-loop
+    (``flat_fori``, auto's non-Pallas route on accelerators) or divide
+    & conquer (``recursive``/``pallas``, crossover ``nb_switch``)
+    schedule otherwise.
 
     Accepts any n: pads to a multiple of 128 with a unit-diagonal
     splice (chol of blockdiag(A, I) is blockdiag(L, I)) and slices the
     factor back out."""
     n = G.shape[0]
-    route = resolve_schedule(n, schedule)
+    route = resolve_schedule(n, G.dtype, schedule)
     if route == "vendor":
-        return lax.linalg.cholesky(G)
+        # the lower triangle only, like every native route (the default
+        # symmetrize_input averages in the upper triangle)
+        return lax.linalg.cholesky(G, symmetrize_input=False)
     npad = -(-n // 128) * 128
     if npad != n:
         # pad first even at small n so chol_unblocked keeps its ib=16
@@ -713,9 +733,13 @@ def cholesky(
         idx = jnp.arange(npad)
         splice = jnp.where(idx >= n, 1.0, 0.0).astype(G.dtype)
         Gp = Gp.at[idx, idx].add(splice)
-        if route in ("recursive", "pallas"):
-            return chol_recursive(Gp, nb_switch, lookahead, route)[:n, :n]
-        return blocked_potrf(Gp, nb)[:n, :n]
+        return _native_chol(Gp, route, nb, nb_switch, lookahead)[:n, :n]
+    return _native_chol(G, route, nb, nb_switch, lookahead)
+
+
+def _native_chol(G, route, nb, nb_switch, lookahead):
     if route in ("recursive", "pallas"):
         return chol_recursive(G, nb_switch, lookahead, route)
+    if route == "flat_fori":
+        return chol_fori(G, nb if G.shape[0] % nb == 0 else 128)
     return blocked_potrf(G, nb)

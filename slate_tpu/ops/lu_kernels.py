@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..internal.precision import hdot as _dot
+
 
 def panel_lu(
     panel: jnp.ndarray, pivot: bool = True, act: int | None = None
@@ -132,7 +134,7 @@ def blocked_getrf(
         # -- trailing update --------------------------------------------
         Lpan = jnp.where((rows >= (k + 1) * nb)[:, None], col_new, 0)
         Urow = jnp.where((cols >= (k + 1) * nb)[None, :], row_new, 0)
-        return G - Lpan @ Urow, perm
+        return G - _dot(Lpan, Urow), perm
 
     perm0 = jnp.arange(Mp, dtype=jnp.int32)
     return lax.fori_loop(0, kt, step, (Gp, perm0))
@@ -244,7 +246,7 @@ def blocked_getrf_tntpiv(
         G = lax.dynamic_update_slice(G, row_new, (k * nb, 0))
         Lpan = jnp.where((rows >= (k + 1) * nb)[:, None], col_new, 0)
         Urow = jnp.where((cols >= (k + 1) * nb)[None, :], row_new, 0)
-        return G - Lpan @ Urow, perm
+        return G - _dot(Lpan, Urow), perm
 
     perm0 = jnp.arange(Mc, dtype=jnp.int32)
     G, perm = lax.fori_loop(0, kt, step, (Gw, perm0))
@@ -260,7 +262,12 @@ def blocked_getrf_tntpiv(
 # the right half at exact shapes, and composes the half permutations.
 # ---------------------------------------------------------------------------
 
-from .chol_kernels import RECURSIVE_MIN_N, _lat_height, split_point
+from .chol_kernels import (
+    RECURSIVE_MIN_N,
+    _lat_height,
+    pallas_compiles,
+    split_point,
+)
 
 
 def _trsm_left_unit(L: jnp.ndarray, B: jnp.ndarray, nb: int) -> jnp.ndarray:
@@ -276,7 +283,7 @@ def _trsm_left_unit(L: jnp.ndarray, B: jnp.ndarray, nb: int) -> jnp.ndarray:
     s = split_point(h)
     B1 = _trsm_left_unit(L[:s, :s], B[:s], nb)
     B2 = _trsm_left_unit(
-        L[s:, s:], B[s:] - L[s:, :s] @ B1, nb
+        L[s:, s:], B[s:] - _dot(L[s:, :s], B1), nb
     )
     return jnp.concatenate([B1, B2], axis=0)
 
@@ -352,7 +359,7 @@ def getrf_recursive(
         S2, restore = canon(
             jnp.concatenate([LU1[s:, :s], R[s:]], axis=1), act - s
         )
-        S = S2[:, s:] - S2[:, :s] @ U12
+        S = S2[:, s:] - _dot(S2[:, :s], U12)
         LU2, p2 = rec(S, act - s)
         LU2, p2 = restore(LU2, p2)
         top = jnp.concatenate([LU1[:s], U12], axis=1)
@@ -370,7 +377,7 @@ def getrf_recursive(
         LU1, p1 = _panel(T[:, :w], act=None if act >= T.shape[0] else act)
         R = T[:, w:][p1]
         U12 = _trsm_left_unit(LU1[:w, :w], R[:w], nb_switch)
-        S = R[w:] - LU1[w:, :w] @ U12
+        S = R[w:] - _dot(LU1[w:, :w], U12)
         frames.append((jnp.concatenate([LU1[:w], U12], axis=1),
                        LU1[w:], p1))
         T, act = S, act - w
@@ -525,7 +532,9 @@ def resolve_lu_schedule(m: int, n: int, dtype, schedule: str = "auto") -> str:
             return "flat_fast"
         return "flat"
     if jax.default_backend() != "cpu" and m == n and n >= RECURSIVE_MIN_N:
-        return "pallas"
+        # the single-loop blocked_getrf where the Pallas panel cannot
+        # run (see chol_kernels.pallas_compiles)
+        return "pallas" if pallas_compiles(dtype) else "flat"
     if lu_supported(dtype):
         return "vendor"
     return "flat"

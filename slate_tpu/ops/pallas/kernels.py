@@ -16,8 +16,9 @@ poorly:
     that XLA turns into gather/scatter, here a single VMEM pass;
   * batched tile transpose feeding MXU-unfriendly layouts.
 
-Every kernel has a jnp reference implementation; `use_pallas()` gates on
-the actual platform, and tests run the Pallas path in interpreter mode.
+Every kernel has a jnp reference implementation; the dispatchers gate on
+the platform (``on_tpu``) and the operand, and tests run the Pallas path
+in interpreter mode.
 """
 
 from __future__ import annotations
@@ -29,20 +30,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific memory spaces; absent on pure-CPU installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform not in ("cpu",)
-    except Exception:  # pragma: no cover
-        return False
+    """Whether the default backend is a TPU (where the kernels compile
+    to Mosaic); every other backend runs the jnp twins or interpret
+    mode."""
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -68,8 +61,7 @@ def _norm_kernel(t_ref, out_ref, *, kind: str):
 def pallas_norm_ok(T, kind: str) -> bool:
     """Mosaic lowering constraints for the norm kernels: 32-bit real
     dtype (the TPU VPU has no f64 vectors) and (8, 128)-divisible tile
-    dims.  This toolchain also aborts on *gridded* pallas_call, so the
-    kernels run grid-free over VMEM-sized chunks under lax.map."""
+    dims."""
     if T.dtype != jnp.float32:
         return False
     N, mb, nb = T.shape
@@ -78,6 +70,24 @@ def pallas_norm_ok(T, kind: str) -> bool:
     if kind == "inf" and mb % 128 != 0:
         return False
     return True
+
+
+def _spec(block_shape, index_map):
+    """BlockSpec whose block indices are int32 even under x64 (python-int
+    indices would lower to 64-bit constants Mosaic cannot take)."""
+    return pl.BlockSpec(
+        block_shape,
+        lambda *g: tuple(jnp.int32(i) for i in index_map(*g)),
+    )
+
+
+def _tile(n: int, sizes=(512, 256, 128, 64, 32, 16, 8)) -> int:
+    """Largest block size in ``sizes`` dividing n (n itself if none
+    does: a full-extent block is always legal)."""
+    for s in sizes:
+        if s <= n and n % s == 0:
+            return s
+    return n
 
 
 def tile_norms_pallas(T: jnp.ndarray, kind: str, interpret: bool = False):
@@ -142,7 +152,7 @@ def tile_norms_reference(T: jnp.ndarray, kind: str):
 def tile_norms(T: jnp.ndarray, kind: str):
     """Dispatch: Pallas on TPU for Mosaic-compatible shapes/dtypes
     (f32, (8,128)-divisible tiles), jnp elsewhere."""
-    if on_tpu() and _HAS_PLTPU and pallas_norm_ok(T, kind):
+    if on_tpu() and pallas_norm_ok(T, kind):
         return tile_norms_pallas(T, kind)
     return tile_norms_reference(T, kind)
 
@@ -165,26 +175,16 @@ def tile_transpose_pallas(T: jnp.ndarray, conj: bool = False, interpret: bool = 
     return pl.pallas_call(
         kernel,
         grid=(N,),
-        in_specs=[pl.BlockSpec((1, mb, nb), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, nb, mb), lambda i: (i, 0, 0)),
+        in_specs=[_spec((1, mb, nb), lambda i: (i, 0, 0))],
+        out_specs=_spec((1, nb, mb), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, nb, mb), T.dtype),
         interpret=interpret,
     )(T)
 
 
-# gridded pallas_call aborts this toolchain's compiler; XLA handles
-# batched transposes well, so the Pallas transpose stays test-only
-_PALLAS_TRANSPOSE_ENABLED = False
-
-
 def tile_transpose(T: jnp.ndarray, conj: bool = False):
-    if (
-        _PALLAS_TRANSPOSE_ENABLED
-        and on_tpu()
-        and _HAS_PLTPU
-        and not jnp.issubdtype(T.dtype, jnp.complexfloating)
-    ):
-        return tile_transpose_pallas(T, conj)
+    # XLA handles batched transposes well; the Pallas transpose above
+    # stays a tested twin
     out = T.transpose(0, 2, 1)
     if conj and jnp.issubdtype(T.dtype, jnp.complexfloating):
         out = jnp.conj(out)
@@ -206,31 +206,45 @@ def butterfly_level_pallas(
         top = D1 x1 + D2 x2 ; bot = D1 x1 - D2 x2
     else:
         top = D1 (x1 + x2) ; bot = D2 (x1 - x2)
-    (matches drivers/lu._apply_butterfly; all rows in one VMEM pass).
+    (matches drivers/lu._apply_butterfly).  Gridded over (half, row
+    block, column block): each step reads the paired (tr, tw) blocks of
+    both halves and writes one half's block.
     """
     two_h, w = X.shape
     h = two_h // 2
+    tr = _tile(h)
+    tw = _tile(w, (512, 256, 128))
+    nr = h // tr
     s = float(np.sqrt(0.5))  # python scalar: weak-typed, not a captured const
 
-    def kernel(x_ref, d1_ref, d2_ref, out_ref):
-        x1 = x_ref[:h, :]
-        x2 = x_ref[h:, :]
-        d1 = d1_ref[:][:, None]
-        d2 = d2_ref[:][:, None]
-        if transpose:
-            top = d1 * x1 + d2 * x2
-            bot = d1 * x1 - d2 * x2
-        else:
-            top = d1 * (x1 + x2)
-            bot = d2 * (x1 - x2)
-        out_ref[:h, :] = s * top
-        out_ref[h:, :] = s * bot
+    def kernel(x1_ref, x2_ref, d1_ref, d2_ref, out_ref):
+        x1, x2 = x1_ref[...], x2_ref[...]
+        d1, d2 = d1_ref[...], d2_ref[...]
+        half = pl.program_id(0)
+
+        @pl.when(half == 0)
+        def _():
+            top = d1 * x1 + d2 * x2 if transpose else d1 * (x1 + x2)
+            out_ref[...] = s * top
+
+        @pl.when(half == 1)
+        def _():
+            bot = d1 * x1 - d2 * x2 if transpose else d2 * (x1 - x2)
+            out_ref[...] = s * bot
 
     return pl.pallas_call(
         kernel,
+        grid=(2, nr, w // tw),
+        in_specs=[
+            _spec((tr, tw), lambda p, i, j: (i, j)),
+            _spec((tr, tw), lambda p, i, j: (i + nr, j)),
+            _spec((tr, 1), lambda p, i, j: (i, 0)),
+            _spec((tr, 1), lambda p, i, j: (i, 0)),
+        ],
+        out_specs=_spec((tr, tw), lambda p, i, j: (p * nr + i, j)),
         out_shape=jax.ShapeDtypeStruct(X.shape, X.dtype),
         interpret=interpret,
-    )(X, D1, D2)
+    )(X, X, D1.reshape(h, 1), D2.reshape(h, 1))
 
 
 def butterfly_level_reference(X, D1, D2, transpose: bool):
@@ -245,7 +259,7 @@ def butterfly_level_reference(X, D1, D2, transpose: bool):
 
 def butterfly_level(X, D1, D2, transpose: bool):
     # Mosaic has no f64 vector support; 32-bit floats only on the chip
-    if on_tpu() and _HAS_PLTPU and X.dtype == jnp.float32:
+    if on_tpu() and X.dtype == jnp.float32 and (X.shape[0] // 2) % 8 == 0:
         return butterfly_level_pallas(X, D1, D2, transpose)
     return butterfly_level_reference(X, D1, D2, transpose)
 
@@ -268,10 +282,10 @@ def tile_geadd_pallas(
         kernel,
         grid=(N,),
         in_specs=[
-            pl.BlockSpec((1, mb, nb), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, mb, nb), lambda i: (i, 0, 0)),
+            _spec((1, mb, nb), lambda i: (i, 0, 0)),
+            _spec((1, mb, nb), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, mb, nb), lambda i: (i, 0, 0)),
+        out_specs=_spec((1, mb, nb), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(A.shape, B.dtype),
         interpret=interpret,
     )(A, B)

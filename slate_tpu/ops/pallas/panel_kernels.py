@@ -6,12 +6,9 @@ ops/qr_fast.py bottom out in panel/small-tile base cases below
 ``nb_switch`` — exactly the layer the reference delegates to hand-tuned
 device tile kernels and that Elmroth & Gustavson identify as the bound
 on recursive factorizations.  This module re-implements those base
-cases as fused Pallas kernels, following the norm/RBT/transpose pattern
-of ops/pallas/kernels.py: every kernel is a single GRID-FREE
-``pl.pallas_call`` (gridded pallas_call aborts this toolchain's
-compiler — see kernels.py), has a jnp reference twin, and takes an
-``interpret`` flag so the CPU CI runs the identical kernel bodies via
-``pl.pallas_call(..., interpret=True)``.
+cases as fused Pallas kernels: every kernel has a jnp reference twin
+and takes an ``interpret`` flag so the CPU CI runs the identical kernel
+bodies via ``pl.pallas_call(..., interpret=True)``.
 
 Kernel families:
 
@@ -23,34 +20,39 @@ Kernel families:
   ``ops/lu_kernels.panel_lu`` (identical arithmetic, so the pivot
   order matches ``lax.linalg.lu`` exactly).
 * ``larft``       — compact-WY T assembly for the QR panel base case:
-  the Gram matrix V^H V, strict-upper extraction, and the
-  diag(1/tau)-with-big-limit splice fused into one kernel building
-  T^{-1}; the small (<= nb) triangular inverse stays on the vendor
-  solve, the same convention as the recursive trsm's <= nb diagonal
-  blocks.
+  the Gram matrix V^H V accumulated over row blocks of V, then the
+  strict-upper extraction and the diag(1/tau)-with-big-limit splice
+  building T^{-1}; the small (<= nb) triangular inverse stays on the
+  vendor solve, the same convention as the recursive trsm's <= nb
+  diagonal blocks.
 * ``syrk_diag`` / ``gemm_sub`` — triangle-aware syrk pieces for the
   Cholesky trailing update: only diagonal nb-blocks pay the
   full-square gemm in-kernel (masked to the lower triangle in the same
-  VMEM pass); off-diagonal blocks are fused multiply-subtract gemms.
+  VMEM pass); off-diagonal blocks are tiled multiply-subtract gemms.
 * ``trsm_lower`` / ``trsm_upper`` — the solve-phase trsm pair behind
   the serve ``phase="solve"`` buckets (the factor cache's top-traffic
-  hit family): blocked forward/backward substitution in one kernel,
-  diagonal KB-blocks inverted by an exact Newton iteration (the
-  residual is strictly-triangular nilpotent, so ceil(log2(KB)) steps
-  reproduce the substitution result exactly in exact arithmetic).
+  hit family): blocked forward/backward substitution, one grid step
+  per KB-row block with the solution resident in VMEM: a full-width
+  MXU update, then column substitution within the diagonal block.
 
-Compiled (non-interpret) dispatch is gated like the norm kernels:
-TPU platform + pltpu import + f32 + (8, 128)-aligned operands.  On any
-other backend/dtype the SAME kernel body runs in interpret mode, which
-lowers to plain XLA ops — this is how ``schedule="pallas"`` reaches
-driver parity on CPU and how artifacts stay custom-call-free.
+Mosaic lowers no ``dynamic_slice`` of a value, so the column loops
+address their current column/row by iota masks and masked reductions
+(exact: one nonzero summed with zeros), and keep their state in the
+output refs rather than in loop-carried values.
 
-Toolchain caveats: besides the gridded-pallas_call abort, this jax
-build's interpret mode cannot initialize COMPLEX pallas outputs
-(``primitives.uninitialized_value`` only handles float/int), so the
-``_run_kernel`` adapter below writes complex results as exact
-real/imag pairs inside the kernel and recombines them outside —
-lossless, and the compiled Mosaic path (f32-only) never sees it.
+Dispatch (the ``chol_base``/``panel_lu``/... entry points): off the TPU
+the kernel body runs in interpret mode, which lowers to plain XLA ops —
+how ``schedule="pallas"`` reaches driver parity on CPU and how serve
+artifacts stay custom-call-free.  On the TPU an eligible operand (f32,
+tiling-aligned, within the VMEM budget) runs compiled Mosaic; any other
+operand takes the jnp reference twin, counted under
+``pallas.reference.<kernel>`` — never the interpreter.
+
+Toolchain caveat: interpret mode cannot initialize COMPLEX pallas
+outputs (``primitives.uninitialized_value`` only handles float/int), so
+``_call`` hands the kernel complex outputs as exact real/imag ref pairs
+behind ``_PairRef`` and recombines them outside — lossless, and the
+compiled Mosaic path (f32-only) never sees it.
 """
 
 from __future__ import annotations
@@ -63,10 +65,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .kernels import _HAS_PLTPU, on_tpu
+from .kernels import _spec, _tile, on_tpu
 
 _HIGHEST = lax.Precision.HIGHEST
+
+#: scoped-VMEM limit handed to Mosaic (the v5e has 128 MiB of VMEM; the
+#: compiler's default scope is 16 MiB) and the budget eligibility checks
+#: hold each call's resident blocks to
+_VMEM_LIMIT = 100 * 2**20
+_VMEM_BUDGET = 64 * 2**20
 
 
 def _conj(x):
@@ -79,76 +88,133 @@ def _mxu_dot(a, b):
     return jnp.dot(a, b, precision=_HIGHEST)
 
 
-def pallas_panel_ok(*arrays) -> bool:
-    """Whether the compiled (non-interpret) Mosaic path supports these
-    operands: f32 only (no f64/complex vector support), every 2-D dim
-    (8, 128)-aligned — the same constraint set as pallas_norm_ok."""
-    for a in arrays:
-        if a.dtype != jnp.float32:
-            return False
-        if a.ndim != 2:
-            return False
-        if a.shape[0] % 8 != 0 or a.shape[1] % 128 != 0:
-            return False
-    return True
+def _dot_nt(a, b):
+    """a @ b^T without materializing the transpose."""
+    return lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), precision=_HIGHEST
+    )
 
 
-def _resolve_interpret(interpret: Optional[bool], *arrays) -> bool:
-    """None = auto: compiled Mosaic only on TPU with eligible operands,
-    interpret mode (plain XLA lowering) everywhere else."""
-    if interpret is not None:
-        return bool(interpret)
-    return not (on_tpu() and _HAS_PLTPU and pallas_panel_ok(*arrays))
+def _aligned(a) -> bool:
+    """f32 with (8, 128)-aligned trailing dims: the operands Mosaic
+    tiles natively (the TPU VPU has no f64/complex vectors)."""
+    return (
+        a.dtype == jnp.float32
+        and a.ndim == 2
+        and a.shape[0] % 8 == 0
+        and a.shape[1] % 128 == 0
+    )
+
+
+def _route(name: str, eligible: bool) -> Optional[bool]:
+    """The ``interpret`` flag for one dispatched kernel call, or None
+    for the jnp reference twin.  Off the TPU the kernel body runs in
+    interpret mode; on the TPU eligible operands compile to Mosaic and
+    the rest take the twin by this counted branch."""
+    if not on_tpu():
+        return True
+    if eligible:
+        return False
+    from ...aux import metrics
+
+    metrics.inc("pallas.reference")
+    metrics.inc(f"pallas.reference.{name}")
+    return None
 
 
 def _real_dtype(dt):
     return jnp.float32 if dt == jnp.dtype(jnp.complex64) else jnp.float64
 
 
-def _run_kernel(body, out_shapes, args, interpret: bool):
-    """Grid-free pallas_call adapter: ``body`` maps input VALUES to
-    output VALUES; refs stay an implementation detail here.  Complex
-    outputs are written as exact real/imag pairs (see the module
-    docstring's toolchain caveat) and recombined outside the kernel."""
+class _PairRef:
+    """A complex output ref stored as exact real/imag ref pairs (the
+    interpret-mode caveat in the module docstring)."""
+
+    def __init__(self, re, im, dtype):
+        self.re, self.im, self.dtype = re, im, dtype
+        self.shape = re.shape
+
+    def __getitem__(self, idx):
+        return lax.complex(self.re[idx], self.im[idx]).astype(self.dtype)
+
+    def __setitem__(self, idx, v):
+        self.re[idx] = jnp.real(v)
+        self.im[idx] = jnp.imag(v)
+
+
+def _call(kernel, out_shapes, args, interpret: bool, grid=(),
+          in_specs=None, out_specs=None):
+    """pallas_call adapter: ``kernel(*in_refs, *out_refs)`` with
+    complex outputs behind ``_PairRef``; sequential grid semantics and
+    the raised VMEM scope on the compiled path."""
     single = not isinstance(out_shapes, (tuple, list))
     outs = [out_shapes] if single else list(out_shapes)
-    expanded = []  # ShapeDtypeStructs handed to pallas_call
-    plan = []  # per logical output: ("plain"|"cplx", first_slot, dtype)
-    for o in outs:
-        if jnp.issubdtype(o.dtype, jnp.complexfloating):
-            rt = _real_dtype(o.dtype)
-            plan.append(("cplx", len(expanded), o.dtype))
-            expanded.append(jax.ShapeDtypeStruct(o.shape, rt))
-            expanded.append(jax.ShapeDtypeStruct(o.shape, rt))
-        else:
-            plan.append(("plain", len(expanded), o.dtype))
-            expanded.append(o)
+    specs = None if out_specs is None else (
+        [out_specs] if single else list(out_specs)
+    )
+    expanded, exp_specs, plan = [], [], []
+    for k, o in enumerate(outs):
+        cplx = jnp.issubdtype(o.dtype, jnp.complexfloating)
+        plan.append((cplx, len(expanded), o.dtype))
+        shape = jax.ShapeDtypeStruct(
+            o.shape, _real_dtype(o.dtype) if cplx else o.dtype
+        )
+        for _ in range(2 if cplx else 1):
+            expanded.append(shape)
+            if specs is not None:
+                exp_specs.append(specs[k])
 
     def kern(*refs):
-        in_refs = refs[: len(args)]
         out_refs = refs[len(args):]
-        vals = body(*[r[...] for r in in_refs])
-        if not isinstance(vals, tuple):
-            vals = (vals,)
-        for (kind, i, _dt), v in zip(plan, vals):
-            if kind == "cplx":
-                out_refs[i][...] = jnp.real(v)
-                out_refs[i + 1][...] = jnp.imag(v)
-            else:
-                out_refs[i][...] = v
+        wrapped = [
+            _PairRef(out_refs[i], out_refs[i + 1], dt) if cplx
+            else out_refs[i]
+            for cplx, i, dt in plan
+        ]
+        kernel(*refs[: len(args)], *wrapped)
 
+    kw = {}
+    if grid:
+        kw.update(grid=grid, in_specs=in_specs, out_specs=exp_specs)
+    if not interpret:
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid) if grid else None,
+            vmem_limit_bytes=_VMEM_LIMIT,
+        )
     raw = pl.pallas_call(
-        kern, out_shape=tuple(expanded), interpret=interpret
+        kern, out_shape=tuple(expanded), interpret=interpret, **kw
     )(*args)
-    if not isinstance(raw, (tuple, list)):
-        raw = (raw,)
-    results = []
-    for kind, i, dt in plan:
-        if kind == "cplx":
-            results.append(lax.complex(raw[i], raw[i + 1]).astype(dt))
-        else:
-            results.append(raw[i])
+    results = [
+        lax.complex(raw[i], raw[i + 1]).astype(dt) if cplx else raw[i]
+        for cplx, i, dt in plan
+    ]
     return results[0] if single else tuple(results)
+
+
+def _loop(n: int, body, carry=None):
+    """``body(j, carry)`` for j in [0, n) with an int32 index: Mosaic
+    has no 64-bit integers, which python-int bounds become under x64."""
+    zero = jnp.int32(0)
+    return lax.fori_loop(
+        zero, jnp.int32(n), body, zero if carry is None else carry
+    )
+
+
+def _col(a, cols, j):
+    """Column j of a 2-D value as (rows, 1), by a masked lane sum."""
+    return jnp.sum(jnp.where(cols == j, a, jnp.zeros_like(a)), axis=1,
+                   keepdims=True)
+
+
+def _row(a, rows, j):
+    """Row j of a 2-D value as (1, cols), by a masked sublane sum."""
+    return jnp.sum(jnp.where(rows == j, a, jnp.zeros_like(a)), axis=0,
+                   keepdims=True)
+
+
+def _at(v, idx, j):
+    """Entry j of a float vector laid out along ``idx``, as a scalar."""
+    return jnp.sum(jnp.where(idx == j, v, jnp.zeros_like(v)))
 
 
 # ---------------------------------------------------------------------------
@@ -156,25 +222,32 @@ def _run_kernel(body, out_shapes, args, interpret: bool):
 # ---------------------------------------------------------------------------
 
 
-def _chol_base_body(a):
-    b = a.shape[0]
+def _chol_base_kernel(g_ref, o_ref):
+    b = o_ref.shape[0]
+    o_ref[...] = g_ref[...]
     rows = lax.broadcasted_iota(jnp.int32, (b, b), 0)
     cols = lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    rvec = lax.broadcasted_iota(jnp.int32, (b, 1), 0)
 
-    def body(j, a):
-        pv = jnp.sqrt(lax.dynamic_slice(a, (j, j), (1, 1))[0, 0])
-        col = lax.dynamic_slice(a, (0, j), (b, 1))
-        rvec = lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+    def body(j, carry):
+        a = o_ref[...]
+        col = _col(a, cols, j)
+        pv = jnp.sqrt(_at(col, rvec, j))
         l = jnp.where(rvec > j, col / pv, jnp.zeros_like(col))
-        # masked rank-1 trailing update in the same pass
-        upd = _mxu_dot(l, _conj(l).T)
-        a = jnp.where((rows > j) & (cols > j), a - upd, a)
+        # l as a row vector (the masked diagonal transposes it), then
+        # the masked rank-1 trailing update in the same pass
+        lrow = jnp.sum(
+            jnp.where(rows == cols, l, jnp.zeros_like(a)), axis=0,
+            keepdims=True,
+        )
+        a = jnp.where((rows > j) & (cols > j), a - l * _conj(lrow), a)
         # write the factored column: pivot on the diagonal, l below;
         # entries above the diagonal pass through (callers tril)
         newcol = jnp.where(rvec == j, pv.astype(a.dtype), l)
-        return jnp.where((cols == j) & (rows >= j), newcol, a)
+        o_ref[...] = jnp.where((cols == j) & (rows >= j), newcol, a)
+        return carry
 
-    return lax.fori_loop(0, b, body, a)
+    _loop(b, body)
 
 
 def chol_base_reference(G: jnp.ndarray) -> jnp.ndarray:
@@ -187,16 +260,18 @@ def chol_base_reference(G: jnp.ndarray) -> jnp.ndarray:
 
 def chol_base_pallas(G: jnp.ndarray, interpret: bool = False) -> jnp.ndarray:
     """Fused unblocked Cholesky of a (b, b) block, one VMEM pass."""
-    return _run_kernel(
-        _chol_base_body,
-        jax.ShapeDtypeStruct(G.shape, G.dtype),
-        (G,),
+    return _call(
+        _chol_base_kernel, jax.ShapeDtypeStruct(G.shape, G.dtype), (G,),
         interpret,
     )
 
 
-def chol_base(G: jnp.ndarray, interpret: Optional[bool] = None) -> jnp.ndarray:
-    return chol_base_pallas(G, interpret=_resolve_interpret(interpret, G))
+def chol_base(G: jnp.ndarray) -> jnp.ndarray:
+    ok = _aligned(G) and G.shape[0] == G.shape[1] and G.shape[0] <= 1024
+    interpret = _route("chol_base", ok)
+    if interpret is None:
+        return chol_base_reference(G)
+    return chol_base_pallas(G, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -204,38 +279,59 @@ def chol_base(G: jnp.ndarray, interpret: Optional[bool] = None) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _panel_lu_body(a, *, pivot: bool, act):
+def _panel_lu_kernel(p_ref, lu_ref, perm_ref, *, pivot: bool, act):
     """Arithmetic replicates ops/lu_kernels.panel_lu exactly (same op
-    sequence -> identical pivot order, identical floats)."""
-    M, nb = a.shape
-    rows = jnp.arange(M)
+    sequence -> identical pivot order, identical floats); the pivot is
+    the first row of maximal magnitude, argmax's tie rule.  Row indices
+    and the permutation ride as f32 (exact below 2^24 rows) and every
+    reduction runs over a lane-dense float array: the f32 factor on the
+    chip was wrong (||PA - LU|| / (||A|| n) = 9.2e-5) with int32 and
+    (M, 1)-shaped reductions in the pivot search."""
+    M, nb = lu_ref.shape
+    f32 = jnp.float32
+    lu_ref[...] = p_ref[...]
+    lanes = lax.broadcasted_iota(jnp.int32, (1, M), 1).astype(f32)
+    perm_ref[...] = lanes
+    rows = lax.broadcasted_iota(jnp.int32, (M, 1), 0)
+    rowsf = rows.astype(f32)
+    cols = lax.broadcasted_iota(jnp.int32, (1, nb), 1)
 
     def body(j, carry):
-        a, perm = carry
-        col = a[:, j]
+        a = lu_ref[...]
+        jf = j.astype(f32)
+        col = _col(a, cols, j)
         if pivot:
             elig = rows >= j if act is None else (rows >= j) & (rows < act)
-            mag = jnp.where(elig, jnp.abs(col), -jnp.inf)
-            piv = jnp.argmax(mag)
+            mag = jnp.abs(a)
+            mag = jnp.where(
+                elig & (cols == j), mag, jnp.asarray(-jnp.inf, mag.dtype)
+            )
+            top = jnp.max(mag)
+            piv = jnp.min(jnp.where(mag == top, rowsf, f32(M)))
         else:
-            piv = j
-        rj = a[j]
-        rp = a[piv]
-        a = a.at[j].set(rp).at[piv].set(rj)
-        pj = perm[j]
-        pp = perm[piv]
-        perm = perm.at[j].set(pp).at[piv].set(pj)
-        pv = a[j, j]
-        safe = jnp.where(pv == 0, jnp.ones_like(pv), pv)
-        l = jnp.where(
-            (rows > j) & (pv != 0), a[:, j] / safe, jnp.zeros(M, a.dtype)
+            piv = jf
+        is_j, is_p = rows == j, rowsf == piv
+        # swap rows j <-> piv
+        rj = _row(a, rows, j)
+        rp = jnp.sum(jnp.where(is_p, a, jnp.zeros_like(a)), axis=0,
+                     keepdims=True)
+        a = jnp.where(is_j, rp, jnp.where(is_p, rj, a))
+        perm = perm_ref[...]
+        pj = _at(perm, lanes, jf)
+        pp = _at(perm, lanes, piv)
+        perm_ref[...] = jnp.where(
+            lanes == jf, pp, jnp.where(lanes == piv, pj, perm)
         )
-        a = a.at[:, j].set(jnp.where(rows > j, l, a[:, j]))
-        urow = jnp.where(jnp.arange(nb) > j, a[j], jnp.zeros(nb, a.dtype))
-        return a - jnp.outer(l, urow), perm
+        pv = _at(rp, cols, j)
+        col = jnp.where(is_j, pv, jnp.where(is_p, _at(rj, cols, j), col))
+        safe = jnp.where(pv == 0, jnp.ones_like(pv), pv)
+        l = jnp.where((rows > j) & (pv != 0), col / safe, jnp.zeros_like(col))
+        a = jnp.where((cols == j) & (rows > j), l, a)
+        urow = jnp.where(cols > j, rp, jnp.zeros_like(rp))
+        lu_ref[...] = a - l * urow
+        return carry
 
-    perm0 = jnp.arange(M, dtype=jnp.int32)
-    return lax.fori_loop(0, min(M, nb), body, (a, perm0))
+    _loop(min(M, nb), body)
 
 
 def panel_lu_reference(
@@ -258,27 +354,27 @@ def panel_lu_pallas(
     is static (the recursive schedule's canonical-height pad rows must
     never pivot)."""
     M, nb = panel.shape
-    return _run_kernel(
-        functools.partial(_panel_lu_body, pivot=pivot, act=act),
+    lu, perm = _call(
+        functools.partial(_panel_lu_kernel, pivot=pivot, act=act),
         (
             jax.ShapeDtypeStruct((M, nb), panel.dtype),
-            jax.ShapeDtypeStruct((M,), jnp.int32),
+            jax.ShapeDtypeStruct((1, M), jnp.float32),
         ),
         (panel,),
         interpret,
     )
+    return lu, perm.reshape(M).astype(jnp.int32)
 
 
 def panel_lu(
-    panel: jnp.ndarray,
-    pivot: bool = True,
-    act: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    panel: jnp.ndarray, pivot: bool = True, act: Optional[int] = None
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    return panel_lu_pallas(
-        panel, pivot=pivot, act=act,
-        interpret=_resolve_interpret(interpret, panel),
-    )
+    # the panel, its factor and ~4 full-size temporaries stay in VMEM
+    ok = _aligned(panel) and 6 * panel.size * 4 <= _VMEM_BUDGET
+    interpret = _route("panel_lu", ok)
+    if interpret is None:
+        return panel_lu_reference(panel, pivot=pivot, act=act)
+    return panel_lu_pallas(panel, pivot=pivot, act=act, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -286,20 +382,33 @@ def panel_lu(
 # ---------------------------------------------------------------------------
 
 
-def _larft_tinv_body(V, taus):
-    """T^{-1} = strict_upper(V^H V) + diag(1/tau) fused in one pass
-    (the tau == 0 large-diagonal limit included)."""
-    nb = V.shape[1]
-    complex_t = jnp.issubdtype(V.dtype, jnp.complexfloating)
-    VhV = _mxu_dot(jnp.conj(V).T if complex_t else V.T, V)
-    rows = lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
-    cols = lax.broadcasted_iota(jnp.int32, (nb, nb), 1)
-    U = jnp.where(cols > rows, VhV, jnp.zeros_like(VhV))
-    big = jnp.asarray(1e30, V.dtype)
-    d = jnp.where(taus != 0, 1.0 / jnp.where(taus == 0, 1, taus), big)
-    return U + jnp.where(
-        rows == cols, d.astype(V.dtype)[None, :], jnp.zeros_like(U)
-    )
+def _larft_kernel(v_ref, t_ref, o_ref, *, nsteps: int):
+    """T^{-1} = strict_upper(V^H V) + diag(1/tau): the Gram matrix
+    accumulates over the row blocks of V in the resident output, the
+    assembly (tau == 0 large-diagonal limit included) runs on the last
+    step."""
+    i = pl.program_id(0)
+    nb = o_ref.shape[0]
+
+    @pl.when(i == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    V = v_ref[...]
+    o_ref[...] += _mxu_dot(_conj(V).T, V)
+
+    @pl.when(i == nsteps - 1)
+    def _():
+        VhV = o_ref[...]
+        rows = lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
+        cols = lax.broadcasted_iota(jnp.int32, (nb, nb), 1)
+        U = jnp.where(cols > rows, VhV, jnp.zeros_like(VhV))
+        taus = t_ref[...]
+        big = jnp.asarray(1e30, VhV.dtype)
+        d = jnp.where(
+            taus != 0, 1.0 / jnp.where(taus == 0, 1, taus), big
+        ).astype(VhV.dtype)
+        o_ref[...] = U + jnp.where(rows == cols, d, jnp.zeros_like(U))
 
 
 def larft_reference(V: jnp.ndarray, taus: jnp.ndarray) -> jnp.ndarray:
@@ -313,19 +422,28 @@ def larft_pallas(
     V: jnp.ndarray, taus: jnp.ndarray, interpret: bool = False
 ) -> jnp.ndarray:
     """Compact-WY T for the QR panel base case: the Gram/assembly stage
-    (the MXU-heavy 2 M nb^2 part) fused in one kernel; the <= nb
-    triangular inverse stays on the vendor solve, the same convention
-    as the recursive schedules' <= nb diagonal trsm blocks."""
-    nb = V.shape[1]
+    (the MXU-heavy 2 M nb^2 part) in one kernel, gridded over row
+    blocks of V so each step fits the scoped VMEM; the <= nb triangular
+    inverse stays on the vendor solve, the same convention as the
+    recursive schedules' <= nb diagonal trsm blocks."""
+    M, nb = V.shape
     if taus.shape[0] < nb:
         taus = jnp.concatenate(
             [taus, jnp.zeros((nb - taus.shape[0],), taus.dtype)]
         )
-    Tinv = _run_kernel(
-        _larft_tinv_body,
+    tm = M if M <= 512 else _tile(M)
+    nsteps = M // tm
+    Tinv = _call(
+        functools.partial(_larft_kernel, nsteps=nsteps),
         jax.ShapeDtypeStruct((nb, nb), V.dtype),
-        (V, taus),
+        (V, taus.reshape(1, nb)),
         interpret,
+        grid=(nsteps,),
+        in_specs=[
+            _spec((tm, nb), lambda i: (i, 0)),
+            _spec((1, nb), lambda i: (0, 0)),
+        ],
+        out_specs=_spec((nb, nb), lambda i: (0, 0)),
     )
     T = lax.linalg.triangular_solve(
         Tinv, jnp.eye(nb, dtype=V.dtype), left_side=True, lower=False
@@ -334,10 +452,12 @@ def larft_pallas(
     return jnp.where(live, T, jnp.zeros_like(T))
 
 
-def larft(
-    V: jnp.ndarray, taus: jnp.ndarray, interpret: Optional[bool] = None
-) -> jnp.ndarray:
-    return larft_pallas(V, taus, interpret=_resolve_interpret(interpret, V))
+def larft(V: jnp.ndarray, taus: jnp.ndarray) -> jnp.ndarray:
+    ok = _aligned(V) and V.shape[1] <= 1024
+    interpret = _route("larft", ok)
+    if interpret is None:
+        return larft_reference(V, taus)
+    return larft_pallas(V, taus, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -345,14 +465,15 @@ def larft(
 # ---------------------------------------------------------------------------
 
 
-def _syrk_diag_body(C, A):
+def _syrk_diag_kernel(c_ref, a_ref, o_ref):
+    C = c_ref[...]
+    A = a_ref[...]
     t = C.shape[0]
     rows = lax.broadcasted_iota(jnp.int32, (t, t), 0)
     cols = lax.broadcasted_iota(jnp.int32, (t, t), 1)
-    upd = _mxu_dot(A, _conj(A).T)
     # entries above the diagonal pass through untouched (_syrk_lower's
     # contract: callers only consume the lower triangle)
-    return jnp.where(rows >= cols, C - upd, C)
+    o_ref[...] = jnp.where(rows >= cols, C - _mxu_dot(A, _conj(A).T), C)
 
 
 def syrk_diag_reference(C: jnp.ndarray, A: jnp.ndarray) -> jnp.ndarray:
@@ -371,24 +492,29 @@ def syrk_diag_pallas(
     """Diagonal nb-block of the trailing update: the one place that
     pays a full-square gemm, fused with the lower-triangle mask in a
     single VMEM pass."""
-    return _run_kernel(
-        _syrk_diag_body,
-        jax.ShapeDtypeStruct(C.shape, C.dtype),
-        (C, A),
+    return _call(
+        _syrk_diag_kernel, jax.ShapeDtypeStruct(C.shape, C.dtype), (C, A),
         interpret,
     )
 
 
-def syrk_diag(
-    C: jnp.ndarray, A: jnp.ndarray, interpret: Optional[bool] = None
-) -> jnp.ndarray:
-    return syrk_diag_pallas(
-        C, A, interpret=_resolve_interpret(interpret, C, A)
+def syrk_diag(C: jnp.ndarray, A: jnp.ndarray) -> jnp.ndarray:
+    ok = (
+        _aligned(C) and _aligned(A)
+        and 4 * (2 * C.size + A.size) * 4 <= _VMEM_BUDGET
     )
+    interpret = _route("syrk_diag", ok)
+    if interpret is None:
+        return syrk_diag_reference(C, A)
+    return syrk_diag_pallas(C, A, interpret=interpret)
 
 
-def _gemm_sub_body(C, A, B):
-    return C - _mxu_dot(A, _conj(B).T)
+def _gemm_sub_kernel(c_ref, a_ref, b_ref, o_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        o_ref[...] = c_ref[...]
+
+    o_ref[...] -= _dot_nt(a_ref[...], _conj(b_ref[...]))
 
 
 def gemm_sub_reference(
@@ -403,80 +529,107 @@ def gemm_sub_reference(
 def gemm_sub_pallas(
     C: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray, interpret: bool = False
 ) -> jnp.ndarray:
-    """Off-diagonal syrk block: fused multiply-subtract C - A B^H."""
-    return _run_kernel(
-        _gemm_sub_body,
+    """Off-diagonal syrk block: multiply-subtract C - A B^H, tiled over
+    (rows of C, cols of C, the contraction)."""
+    (m, n), k = C.shape, A.shape[1]
+    tm, tn, tk = _tile(m), _tile(n, (512, 256, 128)), _tile(k, (512, 256, 128))
+    return _call(
+        _gemm_sub_kernel,
         jax.ShapeDtypeStruct(C.shape, C.dtype),
         (C, A, B),
         interpret,
+        grid=(m // tm, n // tn, k // tk),
+        in_specs=[
+            _spec((tm, tn), lambda i, j, s: (i, j)),
+            _spec((tm, tk), lambda i, j, s: (i, s)),
+            _spec((tn, tk), lambda i, j, s: (j, s)),
+        ],
+        out_specs=_spec((tm, tn), lambda i, j, s: (i, j)),
     )
 
 
-def gemm_sub(
-    C: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
-    interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    return gemm_sub_pallas(
-        C, A, B, interpret=_resolve_interpret(interpret, C, A, B)
-    )
+def gemm_sub(C: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+    ok = _aligned(C) and _aligned(A) and _aligned(B)
+    interpret = _route("gemm_sub", ok)
+    if interpret is None:
+        return gemm_sub_reference(C, A, B)
+    return gemm_sub_pallas(C, A, B, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
 # The solve-phase trsm pair (serve phase="solve" buckets)
 # ---------------------------------------------------------------------------
 
-#: diagonal-block size of the in-kernel substitution; serve bucket
-#: sizes are multiples of 128 so 32 always divides them
-_TRSM_KB = 32
-
 
 def _trsm_kb(n: int) -> int:
-    for kb in (_TRSM_KB, 16, 8, 4, 2, 1):
-        if n % kb == 0:
-            return kb
-    return 1
+    """Row-block size of the substitution: 128 (the lane tile the
+    compiled diagonal block needs) where it divides n."""
+    return _tile(n, (128, 32, 16, 8, 4, 2, 1))
 
 
-def _newton_tri_inv(D, rows, cols, lower: bool, unit: bool, kb: int):
-    """Exact inverse of a triangular (kb, kb) block by Newton iteration:
-    X0 = diag(1/diag), residual I - D X strictly triangular (nilpotent),
-    squared each step -> ceil(log2(kb)) iterations reach it exactly."""
-    keep = cols <= rows if lower else cols >= rows
-    D = jnp.where(keep, D, jnp.zeros_like(D))
-    if unit:
-        D = jnp.where(rows == cols, jnp.ones_like(D), D)
-        X = jnp.where(rows == cols, jnp.ones_like(D), jnp.zeros_like(D))
-    else:
-        dg = jnp.sum(
-            jnp.where(rows == cols, D, jnp.zeros_like(D)), axis=1,
-            keepdims=True,
-        )
-        X = jnp.where(rows == cols, 1.0 / dg, jnp.zeros_like(D))
-    eye2 = jnp.where(rows == cols, jnp.ones_like(D), jnp.zeros_like(D))
-    iters = int(math.ceil(math.log2(kb))) if kb > 1 else 0
-    for _ in range(iters):
-        X = _mxu_dot(X, 2.0 * eye2 - _mxu_dot(D, X))
-    return X
+def _block_subst(D, rhs, lower: bool, unit: bool):
+    """Solve one (kb, kb) triangular diagonal block against rhs by
+    column substitution — backward stable, where an explicit inverse
+    of the block loses accuracy with its condition (the f32 gesv
+    backward error on the chip reached 6.4 eps with Newton-inverted
+    blocks).  Reads only D's own triangle (packed LU storage)."""
+    kb = D.shape[0]
+    rows = lax.broadcasted_iota(jnp.int32, (kb, 1), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (1, kb), 1)
+
+    def body(t, carry):
+        b, X = carry
+        c = t if lower else kb - 1 - t
+        dcol = _col(D, cols, c)
+        xc = _row(b, rows, c)
+        if not unit:
+            xc = xc / _at(dcol, rows, c)
+        X = jnp.where(rows == c, xc, X)
+        later = rows > c if lower else rows < c
+        return jnp.where(later, b - dcol * xc, b), X
+
+    return _loop(kb, body, (rhs, jnp.zeros_like(rhs)))[1]
 
 
-def _trsm_body(L, B, *, lower: bool, unit: bool, kb: int):
+def _trsm_kernel(lrow_ref, d_ref, b_ref, x_ref, *, lower: bool, unit: bool,
+                 kb: int, nblk: int):
+    i = pl.program_id(0)
+    k = i if lower else nblk - 1 - i
+
+    @pl.when(i == 0)
+    def _():
+        x_ref[...] = jnp.zeros(x_ref.shape, x_ref.dtype)
+
+    # full-width update: rows of X not yet solved are still zero, so
+    # the unsolved columns of this row block contribute nothing
+    # (packed-LU storage included: the other triangle multiplies zero
+    # rows)
+    rhs = b_ref[...] - _mxu_dot(lrow_ref[...], x_ref[...])
+    r0 = pl.multiple_of(k * kb, kb)
+    x_ref[pl.ds(r0, kb), :] = _block_subst(d_ref[...], rhs, lower, unit)
+
+
+def trsm_blocked(
+    T: jnp.ndarray, B: jnp.ndarray, lower: bool, unit: bool = False
+) -> jnp.ndarray:
+    """The Pallas pair's algorithm in plain jnp: one ``fori_loop`` over
+    KB-row blocks, each diagonal block solved by ``_block_subst``.
+    Pure XLA ops with one small loop body — custom-call-free on CPU
+    (where the vendor solve is a LAPACK call) and quick to compile for
+    the TPU's emulated f64 (n=8192: the vendor solve compiles ~57 s per
+    sweep for a described v5e)."""
     n, nrhs = B.shape
+    kb = _trsm_kb(n)
     nblk = n // kb
-    rows = lax.broadcasted_iota(jnp.int32, (kb, kb), 0)
-    cols = lax.broadcasted_iota(jnp.int32, (kb, kb), 1)
 
     def blk(i, X):
-        k = i if lower else nblk - 1 - i
-        r0 = k * kb
-        # full-width update: rows of X not yet solved are still zero,
-        # so the unsolved columns of this row block contribute nothing
-        # (packed-LU storage included: the other triangle multiplies
-        # zero rows)
-        Lrow = lax.dynamic_slice(L, (r0, 0), (kb, n))
-        rhs = lax.dynamic_slice(B, (r0, 0), (kb, nrhs)) - _mxu_dot(Lrow, X)
-        D = lax.dynamic_slice(L, (r0, r0), (kb, kb))
-        Dinv = _newton_tri_inv(D, rows, cols, lower, unit, kb)
-        return lax.dynamic_update_slice(X, _mxu_dot(Dinv, rhs), (r0, 0))
+        r0 = (i if lower else nblk - 1 - i) * kb
+        # full-width update, as in _trsm_kernel: unsolved rows are zero
+        Trow = lax.dynamic_slice(T, (r0, 0), (kb, n))
+        rhs = lax.dynamic_slice(B, (r0, 0), (kb, nrhs)) - _mxu_dot(Trow, X)
+        D = lax.dynamic_slice(T, (r0, r0), (kb, kb))
+        Xk = _block_subst(D, rhs, lower, unit)
+        return lax.dynamic_update_slice(X, Xk, (r0, 0))
 
     return lax.fori_loop(0, nblk, blk, jnp.zeros_like(B))
 
@@ -496,11 +649,27 @@ def trsm_upper_reference(U: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
 
 
 def _trsm_pallas_call(T, B, lower, unit, interpret):
-    body = functools.partial(
-        _trsm_body, lower=lower, unit=unit, kb=_trsm_kb(T.shape[0])
-    )
-    return _run_kernel(
-        body, jax.ShapeDtypeStruct(B.shape, B.dtype), (T, B), interpret
+    n, nrhs = B.shape
+    kb = _trsm_kb(n)
+    nblk = n // kb
+
+    def blk(i):
+        return i if lower else nblk - 1 - i
+
+    return _call(
+        functools.partial(
+            _trsm_kernel, lower=lower, unit=unit, kb=kb, nblk=nblk
+        ),
+        jax.ShapeDtypeStruct(B.shape, B.dtype),
+        (T, T, B),
+        interpret,
+        grid=(nblk,),
+        in_specs=[
+            _spec((kb, n), lambda i: (blk(i), 0)),
+            _spec((kb, kb), lambda i: (blk(i), blk(i))),
+            _spec((kb, nrhs), lambda i: (blk(i), 0)),
+        ],
+        out_specs=_spec((n, nrhs), lambda i: (0, 0)),
     )
 
 
@@ -522,18 +691,29 @@ def trsm_upper_pallas(
                              interpret=interpret)
 
 
+def _trsm_ok(T, B) -> bool:
+    """The triangle's row blocks and the resident solution (both
+    double-buffered, the right-hand side lane-padded) within budget."""
+    n = T.shape[0]
+    lanes = -(-B.shape[1] // 128) * 128
+    resident = 2 * (128 * n + n * lanes) * 4
+    return (
+        _aligned(T) and B.dtype == jnp.float32 and n % 128 == 0
+        and resident <= _VMEM_BUDGET
+    )
+
+
 def trsm_lower(
-    L: jnp.ndarray, B: jnp.ndarray, unit: bool = False,
-    interpret: Optional[bool] = None,
+    L: jnp.ndarray, B: jnp.ndarray, unit: bool = False
 ) -> jnp.ndarray:
-    return trsm_lower_pallas(
-        L, B, unit=unit, interpret=_resolve_interpret(interpret, L, B)
-    )
+    interpret = _route("trsm", _trsm_ok(L, B))
+    if interpret is None:
+        return trsm_lower_reference(L, B, unit=unit)
+    return trsm_lower_pallas(L, B, unit=unit, interpret=interpret)
 
 
-def trsm_upper(
-    U: jnp.ndarray, B: jnp.ndarray, interpret: Optional[bool] = None
-) -> jnp.ndarray:
-    return trsm_upper_pallas(
-        U, B, interpret=_resolve_interpret(interpret, U, B)
-    )
+def trsm_upper(U: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+    interpret = _route("trsm", _trsm_ok(U, B))
+    if interpret is None:
+        return trsm_upper_reference(U, B)
+    return trsm_upper_pallas(U, B, interpret=interpret)

@@ -31,7 +31,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .chol_kernels import RECURSIVE_MIN_N, _lat_height, split_point
+from .chol_kernels import (
+    RECURSIVE_MIN_N,
+    _lat_height,
+    pallas_compiles,
+    split_point,
+)
 from .householder import _larfg, larft, materialize_v, apply_block_reflector
 
 from ..internal.precision import hdot as _dot
@@ -299,7 +304,7 @@ def geqrf_flat(G: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return geqrf_fast(G, flat_nb(G.shape[1]))
 
 
-def resolve_qr_schedule(m: int, n: int, schedule: str = "auto") -> str:
+def resolve_qr_schedule(m: int, n: int, dtype, schedule: str = "auto") -> str:
     """The route the eager QR dispatch takes for this shape/backend —
     one resolver shared by the driver's kernel choice and its FLOP
     accounting so the recorded factor.geqrf.* counters always describe
@@ -318,7 +323,7 @@ def resolve_qr_schedule(m: int, n: int, schedule: str = "auto") -> str:
         return "flat"
     if schedule == "auto":
         if jax.default_backend() != "cpu" and m >= n and n >= RECURSIVE_MIN_N:
-            return "pallas"
+            return "pallas" if pallas_compiles(dtype) else "recursive"
         if jax.default_backend() != "cpu" and n >= 1024 and tiled:
             return "flat"
     if _geqrf_xla is not None:

@@ -75,7 +75,7 @@ def _opt_barrier(xs):
             warnings.warn(
                 "optimization_barrier unsupported under vmap on this jax; "
                 "stedc's emulated-f64 fusion guard is dropped — "
-                "eigenvector orthogonality may degrade (BENCH_NOTES r5)",
+                "eigenvector orthogonality may degrade",
                 stacklevel=2,
             )
             _barrier_warned = True

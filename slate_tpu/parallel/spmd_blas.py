@@ -33,26 +33,10 @@ from ..parallel.layout import TileLayout
 
 from ..aux.metrics import instrumented
 
-try:  # jax >= 0.4.35 spells it jax.shard_map
-    from jax import shard_map as _shard_map_mod  # noqa: F401
-
-    _shard_map = jax.shard_map
-except (ImportError, AttributeError):  # pragma: no cover - older spelling
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """shard_map with the varying-manual-axes check disabled: our SPMD
-    kernels mix collective-produced and replicated values in loop carries,
-    which the vma checker (jax >= 0.7) rejects despite being well-defined."""
-    try:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+#: jax.shard_map with the varying-manual-axes check off: our SPMD kernels
+#: mix collective-produced and replicated values in loop carries, which
+#: the checker rejects despite being well-defined
+shard_map = partial(jax.shard_map, check_vma=False)
 
 
 def _acc_dtype(dt):

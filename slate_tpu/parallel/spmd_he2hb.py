@@ -245,7 +245,7 @@ def spmd_unmtr_he2hb_left(
             Tm = conj(Tk).T if trans else Tk
             # the V^H C gram is cancellation-heavy; past ~4096 local
             # rows the chip's f64 emulation drops its compensation
-            # terms on exactly this shape (BENCH_NOTES round-5 cliff;
+            # terms on exactly this shape (an old record, not reproduced;
             # the gathered-path gram was heev's whole orthogonality
             # budget at n=4096) — chunk the tile-stack contraction at
             # <= 2048 rows and accumulate across chunks in f64

@@ -7,7 +7,6 @@ Layout (``SLATE_TPU_ARTIFACTS=/dir`` or ``ArtifactStore(root)``)::
 
     /dir/
       <routine>.<MxNxR>.<dtype>[...].b<batch>.<content12>.slate_exe
-      xla-cache/          # persistent XLA compilation cache (seeded)
       .lock               # cross-process write lock
 
 Each ``.slate_exe`` file is one JSON header line + ``\\n`` + payload
@@ -20,8 +19,9 @@ the runtime half (jax/jaxlib version, backend, device kind, x64 mode —
 
 * ``"export"`` — the payload is ``jax.export`` serialized StableHLO of
   the jitted bucket executable; load deserializes and re-jits it,
-  skipping Python retracing and jax lowering entirely (and, with the
-  seeded XLA cache below, the backend compile too).
+  skipping Python retracing and jax lowering entirely (and, where the
+  operator configured JAX's persistent compilation cache, the backend
+  compile too).
 * ``"cache_seed"`` — ``jax.export`` refused the computation (donated
   or sharded executables are version-dependent), the exported
   module embeds non-portable custom calls (vendor LAPACK on CPU,
@@ -30,9 +30,10 @@ the runtime half (jax/jaxlib version, backend, device kind, x64 mode —
   (``BucketKey.mesh`` — shard_map programs are never trusted across
   processes; the entry is still keyed by its mesh shape, so it cannot
   collide with the single-device fingerprint); the payload is empty
-  and the entry records that the build itself seeded the persistent
-  XLA compilation cache under ``<root>/xla-cache``, so a fresh
-  replica's recompile is a disk hit instead of a cold backend compile.
+  and the entry records that the build itself went to JAX's persistent
+  compilation cache, so where the operator configured one
+  (``JAX_COMPILATION_CACHE_DIR``) a fresh replica's recompile is a
+  disk hit instead of a cold backend compile.
 
 Robustness is the design center, because a persisted artifact is a new
 thing that can be stale, truncated, or corrupt:
@@ -210,7 +211,7 @@ class ArtifactStore:
     artifact" on any filesystem or serialization trouble — the store
     must never take serving down with it."""
 
-    def __init__(self, root: str, seed_xla_cache: bool = True):
+    def __init__(self, root: str):
         self.root = os.path.abspath(root)
         os.makedirs(self.root, exist_ok=True)
         # sync.Lock: plain threading.Lock unless the race plane is on
@@ -220,8 +221,6 @@ class ArtifactStore:
         # this process: the recompile that follows must not pay a
         # redundant export + byte-identical rewrite (see save callers)
         self._cache_seed_verified: set = set()
-        if seed_xla_cache:
-            self._seed_xla_cache()
 
     # -- identity ----------------------------------------------------------
 
@@ -247,43 +246,6 @@ class ArtifactStore:
             self.root, f"{key.label}.b{int(batch)}.{chash}{SUFFIX}"
         )
 
-    def _seed_xla_cache(self) -> None:
-        """Point jax's persistent compilation cache into the store (the
-        cache_seed fallback rung, and a backend-compile accelerator for
-        the export rung's re-jit).  Never stomps an operator-configured
-        cache dir; never raises.
-
-        jax has ONE cache-dir knob per process, so only the first
-        store created in a process can claim it: a later store with a
-        different root counts ``serve.artifact_cache_unseeded`` — its
-        cache_seed entries exist but are not backed by its own
-        ``<root>/xla-cache`` (production replicas run one store; this
-        mostly bites multi-store tests)."""
-        try:
-            import jax
-
-            mine = os.path.join(self.root, "xla-cache")
-            cur = jax.config.jax_compilation_cache_dir
-            if cur:
-                if os.path.abspath(cur) != mine:
-                    # operator-configured, or another store claimed
-                    # the single process-wide knob first
-                    metrics.inc("serve.artifact_cache_unseeded")
-                return
-            jax.config.update("jax_compilation_cache_dir", mine)
-            # cache every entry: serve executables are small programs
-            # whose compiles are still seconds each on accelerators
-            for knob, val in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1),
-            ):
-                try:
-                    jax.config.update(knob, val)
-                except Exception:  # noqa: BLE001 — knob names drift
-                    pass
-        except Exception:  # noqa: BLE001 — seeding is best-effort
-            pass
-
     # -- save --------------------------------------------------------------
 
     def save(self, key: BucketKey, batch: int, jitted, arg_specs) -> str:
@@ -293,7 +255,8 @@ class ArtifactStore:
         non-portable custom calls (vendor LAPACK on CPU, pallas — see
         :func:`nonportable_custom_calls`), the entry is recorded as
         ``cache_seed`` — the build that just happened has already
-        seeded the persistent XLA cache.  Returns the mode written
+        gone to the persistent XLA cache, where one is configured.
+        Returns the mode written
         (``"export"`` | ``"cache_seed"``); never raises."""
         try:
             fp, fields = self.fingerprint(key, batch)
@@ -308,8 +271,8 @@ class ArtifactStore:
                 # the vendor-LAPACK segfault below).  The entry is
                 # still KEYED by its mesh shape (content_fields carries
                 # BucketKey.mesh), so it never collides with the
-                # single-device fingerprint and its build still seeds
-                # the persistent XLA cache for the next replica.
+                # single-device fingerprint and its build still goes
+                # to the persistent XLA cache for the next replica.
                 mode = MODE_CACHE_SEED
                 nonportable = [f"sharded-mesh:{key.mesh}"]
             else:
@@ -390,7 +353,7 @@ class ArtifactStore:
         kind, x64, schedule, precision, ...) -> ``stale``;
         deserialization failure of verified bytes -> ``load_fail``;
         a ``cache_seed`` entry -> ``cache_seed`` (recompile, warmed by
-        the persistent XLA cache).  Fault sites ``artifact_corrupt`` /
+        the persistent XLA cache where one is configured).  Fault sites ``artifact_corrupt`` /
         ``artifact_stale`` / ``artifact_load_fail`` inject each rung."""
         path = self.path_for(key, batch)
         try:
@@ -426,7 +389,7 @@ class ArtifactStore:
             return None
         if header.get("mode") == MODE_CACHE_SEED:
             # nothing to deserialize — the recompile this triggers is
-            # served from the persistent XLA cache seeded at save time
+            # served from the persistent XLA cache, where one is set
             with self._lock:
                 self._cache_seed_verified.add((key, int(batch)))
             self._count(key, batch, "cache_seed")

@@ -2,7 +2,7 @@
 
 Unbounded user shapes must map onto a BOUNDED executable set or every
 new (m, n, nrhs) pays a cold XLA trace+compile (minutes for the staged
-paths, per BENCH_NOTES).  The scheme is the halving-bucket rule already
+paths).  The scheme is the halving-bucket rule already
 proven inside ``drivers/eig.py::_size_bucket_runs``: a size h is
 assigned the smallest S = total / 2^m that still covers it, floored so
 tiny sizes don't multiply compiled bodies.  For serving there is no

@@ -580,7 +580,7 @@ class ExecutableCache:
             if call is not None:
                 # re-jit of deserialized StableHLO: no Python retrace,
                 # no jax lowering; the backend compile is served by the
-                # store-seeded persistent XLA cache.  (Donation is not
+                # persistent XLA cache where one is configured.  (Donation is not
                 # re-applied — exported modules own their buffers.)
                 jitted = jax.jit(call)
                 origin = "artifact"

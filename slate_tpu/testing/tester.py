@@ -777,7 +777,7 @@ ROUTINES: Dict[str, Callable[[Params], tuple]] = {
 # accepts error <= 3*eps under per-routine scalings (test_gemm.cc:192-207
 # and analogues); our metrics use the same scalings but looser factors
 # because (a) the TPU f64 emulation's effective unit roundoff is ~10x
-# IEEE (BENCH_NOTES), and (b) several redesigns trade constants for
+# IEEE (an old record, not reproduced), and (b) several redesigns trade constants for
 # schedule-friendliness.  Factors <= 50 are plain headroom over measured
 # worst cases (~30x eps on-chip).  Every factor > 50 carries its bound:
 #

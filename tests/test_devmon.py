@@ -187,7 +187,7 @@ def test_sample_devices_gauges_and_high_water():
 # ---------------------------------------------------------------------------
 
 
-def test_peaks_for_table_env_and_fallback(monkeypatch):
+def test_peaks_for_table_env_and_unknown_kind(monkeypatch):
     # an ambient deployment override must not shift the default-table
     # assertions below
     monkeypatch.delenv(devmon.PEAKS_ENV, raising=False)
@@ -196,7 +196,12 @@ def test_peaks_for_table_env_and_fallback(monkeypatch):
         p["flops"] / p["bytes_per_s"])
     assert devmon.peaks_for("TPU v4 MegaCore")["flops"] == \
         devmon.DEFAULT_PEAKS["tpu v4"]["flops"]
-    assert devmon.peaks_for("martian accelerator")["source"] == "fallback"
+    # the v5e reports "TPU v5 lite": its own row, ahead of the v5p's
+    v5e = devmon.peaks_for("TPU v5 lite")
+    assert (v5e["flops"], v5e["bytes_per_s"]) == (1.97e14, 8.19e11)
+    assert devmon.peaks_for("TPU v5")["flops"] == 4.59e14
+    with pytest.raises(ValueError, match="martian"):
+        devmon.peaks_for("martian accelerator")
     monkeypatch.setenv(
         devmon.PEAKS_ENV,
         '{"cpu": {"flops": 1e9, "bytes_per_s": 1e8}}',
@@ -218,12 +223,12 @@ def test_peaks_for_table_env_and_fallback(monkeypatch):
         {"flops": 0, "bytes_per_s": 0, "ridge": 0, "source": "x",
          "kind": "x"},
     ) is None
-    # the fallback path honors an env override of the cpu row too
+    # an env row is how an operator adds a kind the table lacks
     monkeypatch.setenv(
-        devmon.PEAKS_ENV, '{"cpu": {"flops": 2e11, "bytes_per_s": 8e10}}'
+        devmon.PEAKS_ENV, '{"martian": {"flops": 2e11, "bytes_per_s": 8e10}}'
     )
     p = devmon.peaks_for("martian accelerator")
-    assert p["source"] == "fallback" and p["flops"] == 2e11
+    assert p["source"] == "env" and p["flops"] == 2e11
 
 
 def test_roofline_classification():
@@ -620,16 +625,14 @@ def test_bench_diff_accepts_wrapped_trajectory_artifacts(tmp_path):
     a.write_text(json.dumps({"rc": 0, "parsed": _bench_doc()}))
     b.write_text(json.dumps({"rc": 0, "parsed": _bench_doc(scale=1.1)}))
     assert _run_tool("bench_diff.py", str(a), str(b)).returncode == 0
-    # an artifact with no parsed payload (BENCH_r05) is unusable: rc 2
+    # an artifact with no parsed payload (a sweep that died) is unusable
     b.write_text(json.dumps({"rc": 124, "tail": "died"}))
     assert _run_tool("bench_diff.py", str(a), str(b)).returncode == 2
 
 
-def test_checked_in_trajectory_pair_and_floor_exist():
-    # the --perf gate's inputs stay in the tree and stay parseable
-    for name in ("BENCH_r03.json", "BENCH_r04.json",
-                 "BENCH_FLOOR_CPU.json"):
-        path = os.path.join(HERE, name)
-        assert os.path.exists(path), name
-    r = _run_tool("bench_diff.py", "BENCH_r03.json", "BENCH_r04.json")
+def test_checked_in_floor_exists_and_diffs_clean():
+    # the --perf gate's input stays in the tree and stays parseable
+    assert os.path.exists(os.path.join(HERE, "BENCH_FLOOR_CPU.json"))
+    r = _run_tool("bench_diff.py", "BENCH_FLOOR_CPU.json",
+                  "BENCH_FLOOR_CPU.json")
     assert r.returncode == 0, r.stdout

@@ -39,6 +39,7 @@ from slate_tpu.fleet.router import (
     HOST_LIVE,
     HOST_REJOINED,
     _rebuild_exc,
+    assign_chips,
 )
 from slate_tpu.integrity.policy import residual_certificate
 from slate_tpu.serve.service import Rejected
@@ -147,6 +148,26 @@ class TestParseFleet:
     def test_unknown_key_names_itself(self):
         with pytest.raises(ValueError, match="bogus"):
             parse_fleet("spawn=1,bogus=3")
+
+
+@pytest.mark.parametrize(
+    "platforms,chips,visible",
+    [
+        (["cpu", "cpu"], 1, [None, None]),  # the CPU drill: no holders
+        (["", ""], 0, [None, None]),  # no chip on this host
+        (["", "cpu"], 1, [None, None]),  # one holder keeps the only chip
+        (["", "tpu"], 4, ["0", "1"]),  # one chip each
+        (["", ""], 1, FleetError),  # two holders, one chip: refused
+    ],
+)
+def test_assign_chips(platforms, chips, visible):
+    envs = [{"JAX_PLATFORMS": p} if p else {} for p in platforms]
+    if visible is FleetError:
+        with pytest.raises(FleetError, match="this host has 1"):
+            assign_chips(envs, chips)
+        return
+    assign_chips(envs, chips)
+    assert [e.get("TPU_VISIBLE_CHIPS") for e in envs] == visible
 
 
 # ---------------------------------------------------------------------------

@@ -420,3 +420,79 @@ def test_serve_pallas_bucket_warm_persist_restore(tmp_path):
     finally:
         metrics.off()
         metrics.reset()
+
+
+# ---------------------------------------------------------------------------
+# auto routing on a TPU-reporting backend (the platform is patched here;
+# nothing runs on it)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "dtype,factor,trsm",
+    [
+        (jnp.float32, ("pallas", "pallas", "pallas"), "pallas"),
+        (jnp.float64, ("flat_fori", "flat", "recursive"), "blocked"),
+        (jnp.complex128, ("flat_fori", "flat", "recursive"), "blocked"),
+    ],
+)
+def test_auto_routes_by_dtype_on_tpu(monkeypatch, dtype, factor, trsm):
+    """auto sends only f32 to the Pallas family on the TPU: the kernels
+    compile for nothing else, and the other dtypes take the single-loop
+    schedules (QR: the jnp recursion) above the crossover."""
+    from slate_tpu.drivers.chol import _solve_trsm_route
+    from slate_tpu.ops.chol_kernels import resolve_schedule
+    from slate_tpu.ops.lu_kernels import resolve_lu_schedule
+    from slate_tpu.ops.qr_fast import resolve_qr_schedule
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 8192
+    assert (
+        resolve_schedule(n, dtype),
+        resolve_lu_schedule(n, n, dtype),
+        resolve_qr_schedule(n, n, dtype),
+    ) == factor
+    assert _solve_trsm_route(n, dtype, "auto") == trsm
+    # below the crossover: the flat schedules and the vendor solve
+    assert resolve_schedule(1024, dtype) == "flat"
+    assert _solve_trsm_route(1024, dtype, "auto") == "vendor"
+
+
+def test_explicit_pallas_on_tpu_takes_counted_twin_not_interpreter(
+    monkeypatch,
+):
+    """On the TPU an operand Mosaic cannot take runs the jnp twin by a
+    counted branch — never interpret mode."""
+    from slate_tpu.aux import metrics
+    from slate_tpu.ops.pallas import kernels as tk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert tk.on_tpu()
+    G = _spd(128, jnp.float64, seed=30)
+    metrics.off()
+    metrics.reset()
+    metrics.on()
+    try:
+        got = pk.chol_base(G)
+        assert metrics.counters().get("pallas.reference.chol_base") == 1
+    finally:
+        metrics.off()
+        metrics.reset()
+    ref = pk.chol_base_reference(G)
+    assert np.array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_trsm_blocked_matches_vendor():
+    """The blocked jnp trsm (auto's non-Pallas route above the
+    crossover, and every explicit native schedule's solve) against the
+    vendor solve, both sweeps and the unit-lower packed-LU case."""
+    n, nrhs = 256, 5
+    B = _rand(n, nrhs, jnp.float64, seed=31)
+    for lower, unit in ((True, False), (True, True), (False, False)):
+        T = _tri(n, jnp.float64, lower, unit, seed=32)
+        got = pk.trsm_blocked(T, B, lower=lower, unit=unit)
+        ref = lax.linalg.triangular_solve(
+            T, B, left_side=True, lower=lower, unit_diagonal=unit
+        )
+        assert np.allclose(np.asarray(got), np.asarray(ref),
+                           atol=1e-12 * n), (lower, unit)

@@ -2,8 +2,8 @@
 """Perf regression sentinel: diff two bench JSON artifacts and fail on
 GFLOP/s regressions or peak-memory growth past thresholds.
 
-    python tools/bench_diff.py BENCH_r03.json BENCH_r04.json
-    python tools/bench_diff.py --baseline BENCH_r04.json live.json
+    python tools/bench_diff.py old.json new.json
+    python tools/bench_diff.py --baseline old.json live.json
     python tools/bench_diff.py --floor BENCH_FLOOR_CPU.json live.json
 
 Accepts either shape of bench artifact: the raw ``bench.py`` stdout
@@ -48,8 +48,7 @@ LAT_FIELDS = ("p99_s",)
 def load_bench(path):
     """The ``{"metric", "value", "extra"}`` payload of either artifact
     shape; None when the file is missing/unreadable/not JSON or has no
-    parsed bench line (e.g. a sweep that died before printing —
-    BENCH_r05) — every unusable input maps to exit code 2, never to
+    parsed bench line (e.g. a sweep that died before printing) — every unusable input maps to exit code 2, never to
     the regression verdict."""
     try:
         with open(path) as f:

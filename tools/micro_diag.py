@@ -6,9 +6,10 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
 os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR", os.path.expanduser("~/.cache/jax_comp")
+    "JAX_COMPILATION_CACHE_DIR", os.path.join(_ROOT, ".jax_cache")
 )
 
 import numpy as np

@@ -1,7 +1,7 @@
 """Factorization throughput breakdown on the chip (round-4 ceiling
 analysis): measures the f64 gemm denominator at n=8192, the three
 factorization totals, their PANEL-ONLY costs, and exact-shape
-trailing-gemm proxies, so BENCH_NOTES.md can attribute the gap between
+trailing-gemm proxies, so the gap can be attributed between
 the factorization rates and the chip's own gemm rate.
 
 Thin wrapper over the shared measurement layer: best-of timing with the
@@ -17,10 +17,11 @@ import json
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
 
 os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR", os.path.expanduser("~/.cache/jax_comp")
+    "JAX_COMPILATION_CACHE_DIR", os.path.join(_ROOT, ".jax_cache")
 )
 
 

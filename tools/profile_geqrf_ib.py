@@ -2,9 +2,11 @@
 decision; see profile_qr_panel.py for the standalone panel numbers that
 refuted the TSQR and CholQR panel alternatives on this chip)."""
 import os, sys, time
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.expanduser("~/.cache/jax_comp"))
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR", os.path.join(_ROOT, ".jax_cache")
+)
 import numpy as np
 
 def main():

@@ -7,9 +7,11 @@ inner fori over sweeps; if XLA keeps the panel carry VMEM-resident the
 HBM traffic drops ~100x, else it matches A.
 """
 import os, sys, time
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.expanduser("~/.cache/jax_comp"))
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR", os.path.join(_ROOT, ".jax_cache")
+)
 import numpy as np
 
 def main():
