@@ -1,13 +1,13 @@
 """Auxiliary subsystems (reference: src/auxiliary/ — Trace, Debug).
 
-- aux.trace: RAII phase tracing + SVG/Chrome timeline + jax.profiler
-  hook.
 - aux.metrics: counters/gauges/timers/histograms registry,
   compile-vs-execute split, cost_analysis FLOP attribution, JSONL
   export (SLATE_TPU_METRICS=/path/out.jsonl).
 - aux.spans: request-scoped span tracer — trace ids, parent/child
   spans, bounded ring-buffer flight recorder
-  (SLATE_TPU_TRACE_RING=N), Chrome trace-event export for Perfetto.
+  (SLATE_TPU_TRACE_RING=N), Chrome trace-event export for Perfetto,
+  and profiler annotations that put driver phases and span blocks on
+  the device trace's clock.
 - aux.faults: deterministic seedable fault injection over named sites
   in the serve/driver dispatch path (SLATE_TPU_FAULTS spec).
 - aux.devmon: device telemetry plane — per-executable cost/memory
@@ -23,4 +23,4 @@
   threading primitives (zero overhead) when off.
 """
 
-from . import devmon, faults, metrics, spans, sync, trace  # noqa: F401
+from . import devmon, faults, metrics, spans, sync  # noqa: F401

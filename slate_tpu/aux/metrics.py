@@ -8,7 +8,7 @@ Design goals, in order:
    (:func:`inc`, :func:`observe`, :class:`phase`, the
    :func:`instrumented` decorator, :func:`instrument_jit` wrappers)
    starts with a single module-level bool check, exactly like
-   ``trace.on_`` in the reference and ``trace._enabled`` here.
+   ``trace.on_`` in the reference's tracer.
 2. **Compile-vs-execute split** — :func:`instrument_jit` wraps a
    ``jax.jit`` callable and detects first dispatch per shape signature
    (cache-size growth), so a recompile storm shows up as the
@@ -20,10 +20,10 @@ Design goals, in order:
    (:func:`costs`, ``flops`` gauges).  Skippable with
    ``SLATE_TPU_METRICS_COST=0`` (the AOT lower/compile is a second
    compile of the same program; cheap on CPU, noticeable on-chip).
-4. **One timeline with trace.py** — phases recorded here also push
-   :class:`trace.Event` rows when tracing is on, so
-   ``trace.finish("trace.svg")`` renders driver phases and metric
-   phases on the same SVG.
+4. **One timeline with the device** — an armed :class:`phase` (every
+   eager ``@instrumented`` driver call) also opens a
+   ``jax.profiler.TraceAnnotation`` of its name, so a profiled run
+   shows it on the host plane, on the same clock as the device ops.
 
 Activation::
 
@@ -80,7 +80,6 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from . import spans as _spans
-from . import trace as _trace
 
 _enabled = False
 _lock = threading.RLock()
@@ -402,8 +401,8 @@ def _hist_counts() -> Dict[str, tuple]:
 
 def _emit_event(name: str, start: float, stop: float, kind: str,
                 extra: Optional[dict] = None) -> None:
-    """Append a timeline event (and mirror it onto trace's timeline so
-    finish("trace.svg") shows metrics phases too)."""
+    """Append a timeline event (and mirror it onto the span ring when
+    spans are on)."""
     global _dropped_events
     ev = {
         "name": name,
@@ -422,10 +421,6 @@ def _emit_event(name: str, start: float, stop: float, kind: str,
             _events.append(ev)
         else:
             _dropped_events += 1
-    if _trace.is_on():
-        with _trace._lock:
-            _trace._events.append(_trace.Event(
-                name, start, stop, threading.get_ident()))
     if _spans.is_on():
         # one flight recorder: metric events (driver phases, per-bucket
         # compile/run dispatches) land on the span ring so a Chrome
@@ -435,14 +430,16 @@ def _emit_event(name: str, start: float, stop: float, kind: str,
 
 class phase:
     """Context manager timing one phase: updates the named timer, appends
-    a timeline event, and (if tracing is on) a trace.Event.
+    a timeline event, and (if spans are on) a span.  While armed it also
+    holds a ``jax.profiler.TraceAnnotation`` of its name open, so a
+    profiled run shows the phase on the host plane.
 
     ``always=True`` measures even with metrics off (for callers that
     need ``.seconds`` as a return value, e.g. heev_staged's stage dict)
     but only *records* when metrics are on.
     """
 
-    __slots__ = ("name", "kind", "always", "seconds", "_start")
+    __slots__ = ("name", "kind", "always", "seconds", "_start", "_ann")
 
     def __init__(self, name: str, kind: str = "phase", always: bool = False):
         self.name = name
@@ -450,17 +447,22 @@ class phase:
         self.always = always
         self.seconds = 0.0
         self._start = 0.0
+        self._ann = None
 
     def __enter__(self):
-        if _enabled or self.always or _trace.is_on() or _spans.is_on():
+        if _enabled or self.always or _spans.is_on():
+            self._ann = _spans.annotation(self.name)
             self._start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         # _start == 0.0 means nothing was armed at __enter__ (also guards
-        # against metrics/trace/spans flipping on mid-block)
+        # against metrics/spans flipping on mid-block)
         if self._start == 0.0 or not (
-            _enabled or self.always or _trace.is_on() or _spans.is_on()
+            _enabled or self.always or _spans.is_on()
         ):
             return False
         stop = time.perf_counter()
@@ -473,10 +475,6 @@ class phase:
                 observe_hist(self.name, self.seconds)
             _emit_event(self.name, self._start, stop, self.kind)
             return False
-        if _trace.is_on():
-            with _trace._lock:
-                _trace._events.append(_trace.Event(
-                    self.name, self._start, stop, threading.get_ident()))
         if _spans.is_on():
             _spans.record(self.name, self._start, stop, kind=self.kind)
         return False
@@ -556,14 +554,14 @@ class deltas:
 
 
 def instrumented(name: str) -> Callable:
-    """Decorator: record one phase per driver call (wall time, both
-    timelines).  With metrics AND tracing off, the overhead is one bool
-    check per call — the drop-in successor of ``trace.traced``."""
+    """Decorator: record one phase per driver call (wall time, the span
+    ring, the profiler's host plane).  With metrics AND spans off, the
+    overhead is one bool check per call."""
 
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, **kw):
-            if not _enabled and not _trace.is_on() and not _spans.is_on():
+            if not _enabled and not _spans.is_on():
                 return fn(*args, **kw)
             import jax
 

@@ -50,8 +50,15 @@ the admission plane's ``shed`` (attrs ``tenant``/``priority``/
 ``level``), ``overload_enter``/``overload_exit`` (attrs ``level``/
 ``sheds``), and ``adaptive_window`` (attrs ``bucket``/``window_s``/
 ``direction`` — the AIMD trajectory, one instant per decision).
-Driver phases (``@metrics.instrumented``) and ``trace.Block`` mirror
-onto the same ring when both layers are on.
+Driver phases (``@metrics.instrumented``) mirror onto the same ring.
+
+On the profiler's clock: a :class:`span` block, and every armed
+``metrics.phase`` (so every eager ``@instrumented`` driver call), also
+holds a ``jax.profiler.TraceAnnotation`` of its name open.  In a
+profiled run these sit on the host plane beside the device ops, and
+name what the host was doing in a gap between them.  Spans opened on
+one thread and ended on another (``queued``, ``request``) stay on the
+ring only: a profiler annotation must end on the thread that began it.
 """
 
 from __future__ import annotations
@@ -81,8 +88,18 @@ _tls = threading.local()  # per-thread stack of context-managed spans
 
 
 def now() -> float:
-    """The span clock (monotonic; shared with metrics/trace phases)."""
+    """The span clock (monotonic; shared with metrics phases)."""
     return time.perf_counter()
+
+
+def annotation(name: str):
+    """An open ``jax.profiler.TraceAnnotation(name)``; the caller exits
+    it on the same thread.  Cheap when no profile is being taken."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class Span:
@@ -271,15 +288,16 @@ def event(name: str, trace: Optional[str] = None, parent=None,
 class span:
     """Context manager for nested single-thread spans: parents onto the
     innermost active span of this thread (or an explicit ``parent`` —
-    e.g. a request's root span held by another thread) and becomes
-    :func:`current` inside the block (so :func:`annotate` reaches it)::
+    e.g. a request's root span held by another thread), becomes
+    :func:`current` inside the block (so :func:`annotate` reaches it)
+    and holds a profiler annotation of its name open::
 
         with spans.span("factor", trace=tr):
             ...
             spans.annotate(iters=3)
     """
 
-    __slots__ = ("name", "trace", "lane", "parent", "attrs", "_sp")
+    __slots__ = ("name", "trace", "lane", "parent", "attrs", "_sp", "_ann")
 
     def __init__(self, name: str, trace: Optional[str] = None,
                  lane: Optional[str] = None, parent=None, **attrs):
@@ -289,6 +307,7 @@ class span:
         self.parent = parent
         self.attrs = attrs
         self._sp: Optional[Span] = None
+        self._ann = None
 
     def __enter__(self) -> Optional[Span]:
         if not _enabled:
@@ -302,6 +321,7 @@ class span:
         tr = self.trace
         if tr is None and isinstance(parent, Span):
             tr = parent.trace
+        self._ann = annotation(self.name)
         self._sp = Span(self.name, trace=tr, parent=parent, lane=self.lane,
                         attrs=self.attrs)
         stack.append(self._sp)
@@ -311,6 +331,8 @@ class span:
         sp = self._sp
         if sp is None:
             return False
+        self._ann.__exit__(None, None, None)
+        self._ann = None
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is sp:
             stack.pop()
@@ -362,22 +384,18 @@ def by_trace() -> Dict[str, List[Span]]:
     return out
 
 
-def export_chrome(path: str, extra=None,
-                  process_name: Optional[str] = None) -> str:
+def export_chrome(path: str, process_name: Optional[str] = None) -> str:
     """Write the ring as Chrome trace-event JSON (the ``traceEvents``
     array format; open in Perfetto / chrome://tracing).  One lane per
     replica/worker: spans with a ``lane`` string share a named tid;
-    lane-less spans fall back to one tid per OS thread.  ``extra``
-    accepts legacy ``trace.Event``-shaped tuples ``(name, start, stop,
-    thread)`` so ``trace.finish()`` can merge both timelines.  Spans
-    carry ``trace``/``span``/``parent`` ids and attrs in ``args``.
+    lane-less spans fall back to one tid per OS thread.  Spans carry
+    ``trace``/``span``/``parent`` ids and attrs in ``args``.
     ``process_name`` labels this process's pid track (Chrome's
     ``process_name`` metadata) — the fleet tier's per-host exports set
     it so ``tools/trace_stitch.py`` renders each host as a named
     process in the stitched view."""
     items = snapshot()
     rows = []  # (name, t0, t1, lane, thread, kind, args)
-    seen = set()  # dedup key against the legacy trace-event mirror
     for sp in items:
         args = {"span": sp.sid}
         if sp.trace is not None:
@@ -387,16 +405,6 @@ def export_chrome(path: str, extra=None,
         args.update(sp.attrs)
         rows.append((sp.name, sp.t_start, sp.t_end, sp.lane, sp.thread,
                      sp.kind, args))
-        seen.add((sp.name, round(sp.t_start, 9), sp.thread))
-    for e in extra or ():
-        name, start_t, stop_t, thread = (
-            (e.name, e.start, e.stop, e.thread) if hasattr(e, "name") else e
-        )
-        # with trace AND spans both on, Block/phase mirror the same
-        # interval into both recorders — emit it once, not twice
-        if (name, round(start_t, 9), thread) in seen:
-            continue
-        rows.append((name, start_t, stop_t, None, thread, "span", {}))
     pid = os.getpid()
     tids: Dict[str, int] = {}
 

@@ -174,19 +174,24 @@ def potrs_from_global(
 
     cplx = jnp.iscomplexobj(Lg)
     route = _solve_trsm_route(Lg.shape[0], Lg.dtype, schedule)
-    if route == "pallas":
-        Y = pk.trsm_lower(Lg, Bg)
+    with jax.named_scope("potrs.trsm_lower"):
+        if route == "pallas":
+            Y = pk.trsm_lower(Lg, Bg)
+        elif route == "blocked":
+            Y = pk.trsm_blocked(Lg, Bg, lower=True)
+        else:
+            Y = lax.linalg.triangular_solve(Lg, Bg, left_side=True,
+                                            lower=True)
+    with jax.named_scope("potrs.trsm_upper"):
+        if route == "vendor":
+            return lax.linalg.triangular_solve(
+                Lg, Y, left_side=True, lower=True, transpose_a=True,
+                conjugate_a=cplx,
+            )
         U = jnp.conj(Lg).T if cplx else Lg.T
-        return pk.trsm_upper(U, Y)
-    if route == "blocked":
-        Y = pk.trsm_blocked(Lg, Bg, lower=True)
-        U = jnp.conj(Lg).T if cplx else Lg.T
+        if route == "pallas":
+            return pk.trsm_upper(U, Y)
         return pk.trsm_blocked(U, Y, lower=False)
-    Y = lax.linalg.triangular_solve(Lg, Bg, left_side=True, lower=True)
-    return lax.linalg.triangular_solve(
-        Lg, Y, left_side=True, lower=True, transpose_a=True,
-        conjugate_a=cplx,
-    )
 
 
 @instrumented("posv")

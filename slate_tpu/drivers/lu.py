@@ -276,9 +276,10 @@ def getrs(
     G = LU.to_global()
     B2 = B.to_global()
     if pivots is not None:
-        B2 = pivots.apply(jnp.pad(B2, ((0, pivots.perm.shape[0] - B2.shape[0]), (0, 0))))[
-            : B.m
-        ]
+        with jax.named_scope("getrs.permute"):
+            B2 = pivots.apply(jnp.pad(
+                B2, ((0, pivots.perm.shape[0] - B2.shape[0]), (0, 0))
+            ))[: B.m]
     X = getrs_from_global(G, B2, resolve_schedule_opts(opts)[0])
     return B._with(data=tiles_from_global(X.astype(B.dtype), B.layout)).shard()
 
@@ -305,16 +306,22 @@ def getrs_from_global(
     from .chol import _solve_trsm_route
 
     route = _solve_trsm_route(LUg.shape[0], LUg.dtype, schedule)
-    if route == "pallas":
-        Y = pk.trsm_lower(LUg, Bg, unit=True)
-        return pk.trsm_upper(LUg, Y)
-    if route == "blocked":
-        Y = pk.trsm_blocked(LUg, Bg, lower=True, unit=True)
-        return pk.trsm_blocked(LUg, Y, lower=False)
-    Y = lax.linalg.triangular_solve(
-        LUg, Bg, left_side=True, lower=True, unit_diagonal=True
-    )
-    return lax.linalg.triangular_solve(LUg, Y, left_side=True, lower=False)
+    with jax.named_scope("getrs.trsm_lower"):
+        if route == "pallas":
+            Y = pk.trsm_lower(LUg, Bg, unit=True)
+        elif route == "blocked":
+            Y = pk.trsm_blocked(LUg, Bg, lower=True, unit=True)
+        else:
+            Y = lax.linalg.triangular_solve(
+                LUg, Bg, left_side=True, lower=True, unit_diagonal=True
+            )
+    with jax.named_scope("getrs.trsm_upper"):
+        if route == "pallas":
+            return pk.trsm_upper(LUg, Y)
+        if route == "blocked":
+            return pk.trsm_blocked(LUg, Y, lower=False)
+        return lax.linalg.triangular_solve(LUg, Y, left_side=True,
+                                           lower=False)
 
 
 @instrumented("gesv")

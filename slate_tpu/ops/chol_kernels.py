@@ -118,22 +118,28 @@ def chol_fori(G: jnp.ndarray, nb: int = 512) -> jnp.ndarray:
     rows = jnp.arange(n)
 
     def step(k, G):
-        Akk = lax.dynamic_slice(G, (k * nb, k * nb), (nb, nb))
-        Lkk = chol_unblocked(Akk)
-        col = lax.dynamic_slice(G, (0, k * nb), (n, nb))
-        sol = lax.linalg.triangular_solve(
-            Lkk, col, left_side=False, lower=True, transpose_a=True,
-            conjugate_a=jnp.iscomplexobj(G),
-        )
-        below = (rows >= (k + 1) * nb)[:, None]
-        Lpan = jnp.where(below, sol, jnp.zeros((), G.dtype))
-        diag_rows = ((rows >= k * nb) & (rows < (k + 1) * nb))[:, None]
-        Lkk_tall = jnp.pad(Lkk, ((0, n - nb), (0, 0)))
-        Lkk_placed = jnp.where(diag_rows, jnp.roll(Lkk_tall, k * nb, axis=0), 0)
-        above = (rows < k * nb)[:, None]
-        newcol = jnp.where(above, jnp.zeros((), G.dtype), Lkk_placed + Lpan)
-        G = lax.dynamic_update_slice(G, newcol, (0, k * nb))
-        return G - _dot(Lpan, _conj(Lpan).T)
+        with jax.named_scope("potrf.panel"):
+            Akk = lax.dynamic_slice(G, (k * nb, k * nb), (nb, nb))
+            Lkk = chol_unblocked(Akk)
+        with jax.named_scope("potrf.trsm"):
+            # the panel below the diagonal block, written back with Lkk
+            col = lax.dynamic_slice(G, (0, k * nb), (n, nb))
+            sol = lax.linalg.triangular_solve(
+                Lkk, col, left_side=False, lower=True, transpose_a=True,
+                conjugate_a=jnp.iscomplexobj(G),
+            )
+            below = (rows >= (k + 1) * nb)[:, None]
+            Lpan = jnp.where(below, sol, jnp.zeros((), G.dtype))
+            diag_rows = ((rows >= k * nb) & (rows < (k + 1) * nb))[:, None]
+            Lkk_tall = jnp.pad(Lkk, ((0, n - nb), (0, 0)))
+            Lkk_placed = jnp.where(diag_rows,
+                                   jnp.roll(Lkk_tall, k * nb, axis=0), 0)
+            above = (rows < k * nb)[:, None]
+            newcol = jnp.where(above, jnp.zeros((), G.dtype),
+                               Lkk_placed + Lpan)
+            G = lax.dynamic_update_slice(G, newcol, (0, k * nb))
+        with jax.named_scope("potrf.update"):
+            return G - _dot(Lpan, _conj(Lpan).T)
 
     return jnp.tril(lax.fori_loop(0, n // nb, step, G))
 
@@ -388,11 +394,15 @@ def _syrk_lower(
 def _chol_rec(G: jnp.ndarray, nb: int, family: str = "recursive") -> jnp.ndarray:
     n = G.shape[0]
     if n <= nb:
-        return _base_chol(G, family)
+        with jax.named_scope("potrf.panel"):
+            return _base_chol(G, family)
     s = split_point(n)
     L11 = _chol_rec(G[:s, :s], nb, family)
-    L21 = _trsm_right_lh(L11, G[s:, :s], nb)
-    L22 = _chol_rec(_syrk_lower(G[s:, s:], L21, nb, family), nb, family)
+    with jax.named_scope("potrf.trsm"):
+        L21 = _trsm_right_lh(L11, G[s:, :s], nb)
+    with jax.named_scope("potrf.update"):
+        S = _syrk_lower(G[s:, s:], L21, nb, family)
+    L22 = _chol_rec(S, nb, family)
     top = jnp.concatenate([L11, jnp.zeros((s, n - s), G.dtype)], axis=1)
     bot = jnp.concatenate([L21, L22], axis=1)
     return jnp.concatenate([top, bot], axis=0)
@@ -424,16 +434,20 @@ def chol_recursive(
     """
     n = G.shape[0]
     if n <= nb_switch:
-        return jnp.tril(_base_chol(G, family))
+        with jax.named_scope("potrf.panel"):
+            return jnp.tril(_base_chol(G, family))
     cols = []
     T = G
     k0 = 0
     peel = max(int(lookahead) - 1, 0)
     while peel > 0 and (n - k0) > 2 * nb_switch:
         w = nb_switch
-        D = _base_chol(T[:w, :w], family)
-        L21 = _trsm_right_lh(D, T[w:, :w], nb_switch)
-        T = _syrk_lower(T[w:, w:], L21, nb_switch, family)
+        with jax.named_scope("potrf.panel"):
+            D = _base_chol(T[:w, :w], family)
+        with jax.named_scope("potrf.trsm"):
+            L21 = _trsm_right_lh(D, T[w:, :w], nb_switch)
+        with jax.named_scope("potrf.update"):
+            T = _syrk_lower(T[w:, w:], L21, nb_switch, family)
         cols.append(
             jnp.concatenate([jnp.zeros((k0, w), G.dtype), D, L21], axis=0)
         )

@@ -105,36 +105,41 @@ def blocked_getrf(
 
     def step(k, carry):
         G, perm = carry
-        # -- panel: roll active rows to the top, factor ----------------
-        col = lax.dynamic_slice(G, (0, k * nb), (Mp, nb))
-        colr = jnp.roll(col, -k * nb, axis=0)
-        active_len = Mp - k * nb
-        colr = jnp.where((rows < active_len)[:, None], colr, jnp.zeros_like(colr))
-        lu_pan, piv = panel_lu(colr)
-        # step permutation in global row space (identity above the panel)
-        act = rows - k * nb
-        mapped = piv[jnp.clip(act, 0, Mp - 1)] + k * nb
-        step_perm = jnp.where(act >= 0, mapped, rows)
-        # -- row exchange across the whole matrix ----------------------
-        G = G[step_perm]
-        perm = perm[step_perm]
-        # -- write the factored panel back (rows >= k*nb) ---------------
-        lu_nat = jnp.roll(lu_pan, k * nb, axis=0)
-        col_cur = lax.dynamic_slice(G, (0, k * nb), (Mp, nb))
-        col_new = jnp.where((rows >= k * nb)[:, None], lu_nat, col_cur)
-        G = lax.dynamic_update_slice(G, col_new, (0, k * nb))
-        # -- U row: Lkk^-1 A(k, j>k) ------------------------------------
-        Lkk = jnp.tril(lu_pan[:nb], -1) + jnp.eye(nb, dtype=G.dtype)
-        row = lax.dynamic_slice(G, (k * nb, 0), (nb, Np))
-        rs = lax.linalg.triangular_solve(
-            Lkk, row, left_side=True, lower=True, unit_diagonal=True
-        )
-        row_new = jnp.where((cols >= (k + 1) * nb)[None, :], rs, row)
-        G = lax.dynamic_update_slice(G, row_new, (k * nb, 0))
-        # -- trailing update --------------------------------------------
-        Lpan = jnp.where((rows >= (k + 1) * nb)[:, None], col_new, 0)
-        Urow = jnp.where((cols >= (k + 1) * nb)[None, :], row_new, 0)
-        return G - _dot(Lpan, Urow), perm
+        with jax.named_scope("getrf.panel"):
+            # roll active rows to the top, factor
+            col = lax.dynamic_slice(G, (0, k * nb), (Mp, nb))
+            colr = jnp.roll(col, -k * nb, axis=0)
+            active_len = Mp - k * nb
+            colr = jnp.where((rows < active_len)[:, None], colr,
+                             jnp.zeros_like(colr))
+            lu_pan, piv = panel_lu(colr)
+            # step permutation in global row space (identity above the
+            # panel)
+            act = rows - k * nb
+            mapped = piv[jnp.clip(act, 0, Mp - 1)] + k * nb
+            step_perm = jnp.where(act >= 0, mapped, rows)
+        with jax.named_scope("getrf.swap"):
+            # row exchange across the whole matrix, then the factored
+            # panel written back (rows >= k*nb)
+            G = G[step_perm]
+            perm = perm[step_perm]
+            lu_nat = jnp.roll(lu_pan, k * nb, axis=0)
+            col_cur = lax.dynamic_slice(G, (0, k * nb), (Mp, nb))
+            col_new = jnp.where((rows >= k * nb)[:, None], lu_nat, col_cur)
+            G = lax.dynamic_update_slice(G, col_new, (0, k * nb))
+        with jax.named_scope("getrf.trsm"):
+            # U row: Lkk^-1 A(k, j>k)
+            Lkk = jnp.tril(lu_pan[:nb], -1) + jnp.eye(nb, dtype=G.dtype)
+            row = lax.dynamic_slice(G, (k * nb, 0), (nb, Np))
+            rs = lax.linalg.triangular_solve(
+                Lkk, row, left_side=True, lower=True, unit_diagonal=True
+            )
+            row_new = jnp.where((cols >= (k + 1) * nb)[None, :], rs, row)
+            G = lax.dynamic_update_slice(G, row_new, (k * nb, 0))
+        with jax.named_scope("getrf.update"):
+            Lpan = jnp.where((rows >= (k + 1) * nb)[:, None], col_new, 0)
+            Urow = jnp.where((cols >= (k + 1) * nb)[None, :], row_new, 0)
+            return G - _dot(Lpan, Urow), perm
 
     perm0 = jnp.arange(Mp, dtype=jnp.int32)
     return lax.fori_loop(0, kt, step, (Gp, perm0))
@@ -347,37 +352,48 @@ def getrf_recursive(
 
         return jnp.pad(X, ((0, Mc - M), (0, 0))), restore
 
+    def panel(X, act):
+        with jax.named_scope("getrf.panel"):
+            return _panel(X, act=None if act >= X.shape[0] else act)
+
     def rec(G, act):
         # invariant: rows >= act of G are exact zeros (never pivotable)
         M, n = G.shape
         if n <= nb_switch:
-            return _panel(G, act=None if act >= M else act)
+            return panel(G, act)
         s = split_point(n)
         LU1, p1 = rec(G[:, :s], act)
-        R = G[:, s:][p1]
-        U12 = _trsm_left_unit(LU1[:s, :s], R[:s], nb_switch)
-        S2, restore = canon(
-            jnp.concatenate([LU1[s:, :s], R[s:]], axis=1), act - s
-        )
-        S = S2[:, s:] - _dot(S2[:, :s], U12)
+        with jax.named_scope("getrf.swap"):
+            R = G[:, s:][p1]
+        with jax.named_scope("getrf.trsm"):
+            U12 = _trsm_left_unit(LU1[:s, :s], R[:s], nb_switch)
+        with jax.named_scope("getrf.update"):
+            S2, restore = canon(
+                jnp.concatenate([LU1[s:, :s], R[s:]], axis=1), act - s
+            )
+            S = S2[:, s:] - _dot(S2[:, :s], U12)
         LU2, p2 = rec(S, act - s)
-        LU2, p2 = restore(LU2, p2)
-        top = jnp.concatenate([LU1[:s], U12], axis=1)
-        bot = jnp.concatenate([LU1[s:][p2], LU2], axis=1)
-        perm = jnp.concatenate([p1[:s], p1[s:][p2]])
-        return jnp.concatenate([top, bot], axis=0), perm
+        with jax.named_scope("getrf.swap"):
+            LU2, p2 = restore(LU2, p2)
+            top = jnp.concatenate([LU1[:s], U12], axis=1)
+            bot = jnp.concatenate([LU1[s:][p2], LU2], axis=1)
+            perm = jnp.concatenate([p1[:s], p1[s:][p2]])
+            return jnp.concatenate([top, bot], axis=0), perm
 
     if n <= nb_switch:
-        return _panel(G)
+        return panel(G, m)
     peel = max(int(lookahead) - 1, 0)
     frames = []  # (top_row_block, L_below, step perm), outermost first
     T, act = G, m
     while peel > 0 and (T.shape[1]) > 2 * nb_switch:
         w = nb_switch
-        LU1, p1 = _panel(T[:, :w], act=None if act >= T.shape[0] else act)
-        R = T[:, w:][p1]
-        U12 = _trsm_left_unit(LU1[:w, :w], R[:w], nb_switch)
-        S = R[w:] - _dot(LU1[w:, :w], U12)
+        LU1, p1 = panel(T[:, :w], act)
+        with jax.named_scope("getrf.swap"):
+            R = T[:, w:][p1]
+        with jax.named_scope("getrf.trsm"):
+            U12 = _trsm_left_unit(LU1[:w, :w], R[:w], nb_switch)
+        with jax.named_scope("getrf.update"):
+            S = R[w:] - _dot(LU1[w:, :w], U12)
         frames.append((jnp.concatenate([LU1[:w], U12], axis=1),
                        LU1[w:], p1))
         T, act = S, act - w
@@ -387,11 +403,12 @@ def getrf_recursive(
     # composing permutations innermost-out (each frame nests exactly
     # like a recursion half)
     bot, p = LUr, pr
-    for top, Lw, p1 in reversed(frames):
-        w = top.shape[0]
-        bot = jnp.concatenate([Lw[p], bot], axis=1)
-        bot = jnp.concatenate([top, bot], axis=0)
-        p = jnp.concatenate([p1[:w], p1[w:][p]])
+    with jax.named_scope("getrf.swap"):
+        for top, Lw, p1 in reversed(frames):
+            w = top.shape[0]
+            bot = jnp.concatenate([Lw[p], bot], axis=1)
+            bot = jnp.concatenate([top, bot], axis=0)
+            p = jnp.concatenate([p1[:w], p1[w:][p]])
     return bot, p
 
 

@@ -129,6 +129,7 @@ def tile_norms_pallas(T: jnp.ndarray, kind: str, interpret: bool = False):
         kernel,
         out_shape=jax.ShapeDtypeStruct((CH, out_cols), real),
         interpret=interpret,
+        name=f"tile_norms_{kind}",
     )
     chunks = T.reshape(Np // CH, CH * mb, nb)
     out = lax.map(call, chunks).reshape(Np, out_cols)[:N]
@@ -179,6 +180,7 @@ def tile_transpose_pallas(T: jnp.ndarray, conj: bool = False, interpret: bool = 
         out_specs=_spec((1, nb, mb), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, nb, mb), T.dtype),
         interpret=interpret,
+        name="tile_transpose",
     )(T)
 
 
@@ -244,6 +246,7 @@ def butterfly_level_pallas(
         out_specs=_spec((tr, tw), lambda p, i, j: (p * nr + i, j)),
         out_shape=jax.ShapeDtypeStruct(X.shape, X.dtype),
         interpret=interpret,
+        name="butterfly_level",
     )(X, X, D1.reshape(h, 1), D2.reshape(h, 1))
 
 
@@ -288,4 +291,5 @@ def tile_geadd_pallas(
         out_specs=_spec((1, mb, nb), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(A.shape, B.dtype),
         interpret=interpret,
+        name="tile_geadd",
     )(A, B)

@@ -142,11 +142,12 @@ class _PairRef:
         self.im[idx] = jnp.imag(v)
 
 
-def _call(kernel, out_shapes, args, interpret: bool, grid=(),
+def _call(kernel, out_shapes, args, interpret: bool, name: str, grid=(),
           in_specs=None, out_specs=None):
     """pallas_call adapter: ``kernel(*in_refs, *out_refs)`` with
     complex outputs behind ``_PairRef``; sequential grid semantics and
-    the raised VMEM scope on the compiled path."""
+    the raised VMEM scope on the compiled path.  ``name`` is the
+    kernel's role, which names its custom call in HLO and in traces."""
     single = not isinstance(out_shapes, (tuple, list))
     outs = [out_shapes] if single else list(out_shapes)
     specs = None if out_specs is None else (
@@ -182,7 +183,7 @@ def _call(kernel, out_shapes, args, interpret: bool, grid=(),
             vmem_limit_bytes=_VMEM_LIMIT,
         )
     raw = pl.pallas_call(
-        kern, out_shape=tuple(expanded), interpret=interpret, **kw
+        kern, out_shape=tuple(expanded), interpret=interpret, name=name, **kw
     )(*args)
     results = [
         lax.complex(raw[i], raw[i + 1]).astype(dt) if cplx else raw[i]
@@ -262,7 +263,7 @@ def chol_base_pallas(G: jnp.ndarray, interpret: bool = False) -> jnp.ndarray:
     """Fused unblocked Cholesky of a (b, b) block, one VMEM pass."""
     return _call(
         _chol_base_kernel, jax.ShapeDtypeStruct(G.shape, G.dtype), (G,),
-        interpret,
+        interpret, "chol_panel",
     )
 
 
@@ -362,6 +363,7 @@ def panel_lu_pallas(
         ),
         (panel,),
         interpret,
+        "lu_panel",
     )
     return lu, perm.reshape(M).astype(jnp.int32)
 
@@ -438,6 +440,7 @@ def larft_pallas(
         jax.ShapeDtypeStruct((nb, nb), V.dtype),
         (V, taus.reshape(1, nb)),
         interpret,
+        "larft",
         grid=(nsteps,),
         in_specs=[
             _spec((tm, nb), lambda i: (i, 0)),
@@ -494,7 +497,7 @@ def syrk_diag_pallas(
     single VMEM pass."""
     return _call(
         _syrk_diag_kernel, jax.ShapeDtypeStruct(C.shape, C.dtype), (C, A),
-        interpret,
+        interpret, "syrk_diag",
     )
 
 
@@ -538,6 +541,7 @@ def gemm_sub_pallas(
         jax.ShapeDtypeStruct(C.shape, C.dtype),
         (C, A, B),
         interpret,
+        "gemm_sub",
         grid=(m // tm, n // tn, k // tk),
         in_specs=[
             _spec((tm, tn), lambda i, j, s: (i, j)),
@@ -663,6 +667,7 @@ def _trsm_pallas_call(T, B, lower, unit, interpret):
         jax.ShapeDtypeStruct(B.shape, B.dtype),
         (T, T, B),
         interpret,
+        "trsm_lower" if lower else "trsm_upper",
         grid=(nblk,),
         in_specs=[
             _spec((kb, n), lambda i: (blk(i), 0)),

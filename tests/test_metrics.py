@@ -1,7 +1,6 @@
 """Observability layer tests: aux/metrics.py (counters/gauges/timers,
 compile-vs-run split, cost_analysis capture, JSONL round-trip,
-zero-overhead-when-off, thread safety, the fallback/precision counters)
-and aux/trace.py (Block nesting, traced, SVG output, shared timeline)."""
+zero-overhead-when-off, thread safety, the fallback/precision counters)."""
 
 import json
 import threading
@@ -9,21 +8,17 @@ import threading
 import numpy as np
 import pytest
 
-from slate_tpu.aux import metrics, trace
+from slate_tpu.aux import metrics
 
 
 @pytest.fixture(autouse=True)
 def _clean_registry():
-    """Every test starts and ends with metrics+trace off and empty."""
+    """Every test starts and ends with metrics off and empty."""
     metrics.off()
     metrics.reset()
-    trace.off()
-    trace.clear()
     yield
     metrics.off()
     metrics.reset()
-    trace.off()
-    trace.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -216,14 +211,14 @@ def test_off_records_nothing():
 
 
 def test_instrumented_off_is_single_bool_check():
-    """With metrics AND trace off the wrapper takes the early-return
+    """With metrics AND spans off the wrapper takes the early-return
     branch: no Timer object, no dict writes (the zero-overhead contract,
     like trace.on_ in the reference)."""
     calls = []
 
     @metrics.instrumented("probe")
     def fn():
-        calls.append(metrics.is_on() or trace.is_on())
+        calls.append(metrics.is_on())
 
     fn()
     assert calls == [False]
@@ -447,80 +442,3 @@ def test_he2hb_f32_band_accuracy(rng):
     wa = np.linalg.eigvalsh(S.astype(np.float64))
     scale = max(np.abs(wa).max(), 1.0)
     assert np.abs(wb - wa).max() / scale < 50 * n * np.finfo(np.float32).eps
-
-
-# ---------------------------------------------------------------------------
-# trace.py coverage: Block nesting, traced, SVG, shared timeline
-# ---------------------------------------------------------------------------
-
-
-def test_trace_block_nesting(tmp_path):
-    trace.on()
-    with trace.Block("outer"):
-        with trace.Block("inner"):
-            pass
-    trace.off()
-    names = {e.name for e in trace._events}
-    assert names == {"outer", "inner"}
-    inner = next(e for e in trace._events if e.name == "inner")
-    outer = next(e for e in trace._events if e.name == "outer")
-    # nested block is contained in the outer interval
-    assert outer.start <= inner.start and inner.stop <= outer.stop
-
-
-def test_traced_decorator_records_only_when_on():
-    calls = []
-
-    @trace.traced("fn")
-    def fn():
-        calls.append(1)
-
-    fn()
-    assert trace._events == [] and calls == [1]
-    trace.on()
-    fn()
-    assert [e.name for e in trace._events] == ["fn"]
-
-
-def test_trace_svg_output(tmp_path):
-    trace.on()
-    with trace.Block("phase_a"):
-        pass
-    with trace.Block("phase_b"):
-        pass
-    path = str(tmp_path / "trace.svg")
-    out = trace.finish(path)
-    assert out == path
-    svg = open(path).read()
-    assert svg.startswith("<svg")
-    assert "phase_a" in svg and "phase_b" in svg
-
-
-def test_metrics_phase_lands_on_trace_timeline(tmp_path):
-    """Metrics phases and trace blocks share one timeline: finish() must
-    render phases recorded through metrics while tracing is on."""
-    trace.on()
-    metrics.on()
-    with metrics.phase("metric_phase"):
-        pass
-    with trace.Block("trace_block"):
-        pass
-    path = str(tmp_path / "t.svg")
-    trace.finish(path)
-    svg = open(path).read()
-    assert "metric_phase" in svg and "trace_block" in svg
-
-
-def test_instrumented_records_trace_when_metrics_off():
-    """@instrumented subsumes trace.traced: tracing alone still gets the
-    block even with the metrics registry off."""
-
-    @metrics.instrumented("drv")
-    def drv():
-        return 7
-
-    trace.on()
-    assert drv() == 7
-    assert [e.name for e in trace._events] == ["drv"]
-    metrics.on()
-    assert metrics.timers() == {}  # metrics stayed off during the call

@@ -1,7 +1,7 @@
 """Request-lifecycle tracing tests: aux/spans.py (ring-buffer bounds,
 nesting/ids, zero-overhead-off, Chrome export schema round-trip),
 the serve lifecycle span chain (admit -> queued -> execute -> deliver),
-the chaos-integrated retry/backoff span, trace.py unification, and the
+the chaos-integrated retry/backoff span, and the
 SLO surface (oldest_queued_s gauge, slo_burn tiers, health latency)."""
 
 import json
@@ -10,25 +10,23 @@ import threading
 import numpy as np
 import pytest
 
-from slate_tpu.aux import faults, metrics, spans, trace
+from slate_tpu.aux import faults, metrics, spans
 
 
 @pytest.fixture(autouse=True)
 def _clean_registry():
-    """Every test starts and ends with spans/metrics/trace/faults off
-    and empty."""
-    for mod in (metrics, spans, trace):
+    """Every test starts and ends with spans/metrics/faults off and
+    empty."""
+    for mod in (metrics, spans):
         mod.off()
     metrics.reset()
     spans.clear()
-    trace.clear()
     faults.reset()
     yield
-    for mod in (metrics, spans, trace):
+    for mod in (metrics, spans):
         mod.off()
     metrics.reset()
     spans.clear()
-    trace.clear()
     faults.reset()
 
 
@@ -197,44 +195,6 @@ def test_chrome_export_schema_round_trip(tmp_path):
     assert inst["name"] == "breaker_open" and inst["args"]["bucket"] == "b"
     # tids are stable ints shared per lane
     assert complete["child"]["tid"] == inst["tid"]
-
-
-def test_export_merges_legacy_trace_events(tmp_path):
-    """trace.finish() default output is Chrome JSON over BOTH the
-    legacy event list and the span ring (the unification satellite)."""
-    trace.on()
-    spans.on()
-    with trace.Block("legacy_block"):
-        pass
-    with spans.span("ring_span"):
-        pass
-    path = str(tmp_path / "merged.json")
-    assert trace.finish(path) == path
-    evs = json.load(open(path))["traceEvents"]
-    names = [e["name"] for e in evs if e.get("ph") == "X"]
-    assert {"legacy_block", "ring_span"} <= set(names)
-    # with both layers on, Block mirrors into BOTH recorders — the
-    # export must dedup, not render every driver phase twice
-    assert names.count("legacy_block") == 1
-    # the .svg spelling keeps the legacy renderer
-    svg = trace.finish(str(tmp_path / "t.svg"))
-    assert open(svg).read().startswith("<svg")
-
-
-def test_trace_block_feeds_span_ring_without_trace_on():
-    """Block/traced emit into the ring even when the legacy trace layer
-    is off — spans is the successor recorder."""
-
-    @trace.traced("drv")
-    def drv():
-        return 1
-
-    spans.on()
-    assert drv() == 1
-    with trace.Block("blk"):
-        pass
-    assert {s.name for s in spans.snapshot()} == {"drv", "blk"}
-    assert trace._events == []  # legacy list untouched while trace off
 
 
 def test_instrumented_driver_lands_on_ring():
