@@ -287,7 +287,8 @@ def pallas_compiles(dtype) -> bool:
     """Whether the Pallas panel kernels compile for this dtype here:
     f32 on the TPU only (Mosaic has no f64/complex vectors).  ``auto``
     takes the ``pallas`` family above the crossover exactly then; every
-    other dtype takes a single-loop schedule, because the TPU emulates
+    other dtype takes a loop schedule (``chol_fori``, or
+    ``blocked_getrf``'s at most 4 loops), because the TPU emulates
     f64 and each op unrolled into a program costs about a second of
     compile (n=8192 f64 posv for a described v5e: recursive 515 s,
     ``chol_fori`` 11 s)."""

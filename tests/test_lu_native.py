@@ -85,3 +85,51 @@ def test_getrf_forced_native(rng, monkeypatch):
     assert int(info) == 0
     err = checks.solve_residual(A0, np.asarray(X.to_global()), B0)
     assert checks.passed(err, np.float64, factor=30), err
+
+
+# Above RECURSIVE_MIN_N blocked_getrf runs its steps in at most four loops
+# at exact trailing shapes; each must reproduce the single full-shape loop.
+@pytest.mark.parametrize(
+    "m,n,nb",
+    [
+        (2560, 2560, 256),  # kt=10: segments of 3, 3, 3, 1 steps
+        (2560, 2048, 256),  # tall
+        (2048, 2560, 256),  # wide
+    ],
+)
+def test_segmented_getrf_matches_single_loop(rng, m, n, nb):
+    A = rng.standard_normal((m, n))
+    kt = min(m, n) // nb
+    assert len(lu_kernels._flat_segments(m, n, nb)) == 4
+    LU, perm = lu_kernels.blocked_getrf(np.asarray(A), nb)
+    LU0, perm0 = lu_kernels._getrf_steps(np.asarray(A), nb, kt)
+    np.testing.assert_array_equal(np.asarray(perm), np.asarray(perm0))
+    np.testing.assert_allclose(np.asarray(LU), np.asarray(LU0), atol=1e-12)
+
+
+def test_segmented_getrf_singular(rng):
+    """Zero column inside a later segment: finite output, zero U
+    diagonal there, as the single loop gives."""
+    n, nb = 2048, 256
+    A = rng.standard_normal((n, n))
+    A[:, 1100] = 0.0
+    LU, perm = lu_kernels.blocked_getrf(np.asarray(A), nb)
+    LU0, perm0 = lu_kernels._getrf_steps(np.asarray(A), nb, n // nb)
+    LU = np.asarray(LU)
+    assert np.isfinite(LU).all()
+    assert LU[1100, 1100] == 0.0
+    np.testing.assert_array_equal(np.asarray(perm), np.asarray(perm0))
+    np.testing.assert_allclose(LU, np.asarray(LU0), atol=1e-12)
+
+
+def test_blocked_getrf_small_is_single_loop():
+    """Below RECURSIVE_MIN_N blocked_getrf lowers to exactly the single
+    full-shape loop."""
+    import jax
+    import jax.numpy as jnp
+
+    n, nb = 1024, 256
+    G = jax.ShapeDtypeStruct((n, n), jnp.float64)
+    seg = jax.jit(lambda G: lu_kernels.blocked_getrf(G, nb)).lower(G)
+    one = jax.jit(lambda G: lu_kernels._getrf_steps(G, nb, n // nb)).lower(G)
+    assert seg.as_text() == one.as_text()

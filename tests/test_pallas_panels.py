@@ -438,7 +438,7 @@ def test_serve_pallas_bucket_warm_persist_restore(tmp_path):
 )
 def test_auto_routes_by_dtype_on_tpu(monkeypatch, dtype, factor, trsm):
     """auto sends only f32 to the Pallas family on the TPU: the kernels
-    compile for nothing else, and the other dtypes take the single-loop
+    compile for nothing else, and the other dtypes take the loop
     schedules (QR: the jnp recursion) above the crossover."""
     from slate_tpu.drivers.chol import _solve_trsm_route
     from slate_tpu.ops.chol_kernels import resolve_schedule

@@ -203,11 +203,13 @@ def test_flops_ratio_acceptance_n2048():
     assert ch["exec"] / ch["model"] <= 1.35, ch
     lu = getrf_schedule_flops(2048, 2048, 512, "recursive", nb_switch=256)
     assert lu["exec"] / lu["model"] <= 1.35, lu
-    # and the flat loops really are the waste the recursion removes
+    # and the flat loops really are the waste the recursion removes:
+    # the segmented flat getrf at n/nb = 4 runs one step per segment
     chf = chol_schedule_flops(2048, 512, "flat_fori")
     luf = getrf_schedule_flops(2048, 2048, 512, "flat")
     assert chf["exec"] / chf["model"] > 2.0
-    assert luf["exec"] / luf["model"] > 2.0
+    assert luf["exec"] / luf["model"] == pytest.approx(2.109375)
+    assert lu["exec"] < luf["exec"]
 
 
 def test_compile_units_bound_n2048():
@@ -231,10 +233,20 @@ def test_recursive_beats_flat_at_scale():
         assert ch_r["exec"] < ch_f["exec"] / 2
         lu_r = getrf_schedule_flops(n, n, 512, "recursive", nb_switch=256)
         lu_f = getrf_schedule_flops(n, n, 512, "flat")
-        assert lu_r["exec"] < lu_f["exec"] / 2
+        assert lu_r["exec"] < lu_f["exec"]
         qr_r = geqrf_schedule_flops(n, n, 512, "recursive", nb_switch=256)
         qr_f = geqrf_schedule_flops(n, n, 512, "flat")
         assert qr_r["exec"] < qr_f["exec"]
+
+
+def test_flat_getrf_segments_n8192():
+    """The flat getrf at n=8192, nb=512 runs four exact-shape loops of
+    four steps (8192, 6144, 4096, 2048): 1.58x the model FLOPs, where
+    one full-shape loop ran 3.28x."""
+    lu = getrf_schedule_flops(8192, 8192, 512, "flat")
+    assert lu["exec"] / lu["model"] == pytest.approx(1.58203125)
+    gemms = sorted(u for u in lu["units"] if u[0] == "gemm")
+    assert gemms == [("gemm", s, 512, s) for s in (2048, 4096, 6144, 8192)]
 
 
 # ---------------------------------------------------------------------------
