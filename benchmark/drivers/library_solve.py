@@ -23,23 +23,18 @@ import time
 import numpy as np
 
 import gen
+import harness
 import reference
 
 
-def solve_fn(st, cfg, opts):
+def solve_fn(st, cfg, opts, root=harness.ROOT):
     """The timed program, as a function of global (n, n) and (n, nrhs)
-    device arrays on one chip."""
+    device arrays on one chip: the call of ``routines/<routine>.py``."""
+    call = harness.routine(cfg["routine"], root).call
     nb = int(cfg["nb"])
 
     def solve(A, B):
-        Bm = st.Matrix.from_global(B, nb)
-        if cfg["routine"] == "posv":
-            Am = st.HermitianMatrix.from_global(A, nb, uplo=st.Uplo.Lower)
-            X, _L, _info = st.posv(Am, Bm, opts)
-        else:
-            X, _LU, _piv, _info = st.gesv(st.Matrix.from_global(A, nb), Bm,
-                                          opts)
-        return X.to_global()
+        return call(st, A, B, nb, opts)
 
     return solve
 
@@ -76,7 +71,7 @@ class Run:
             raise ValueError("library_solve runs on one chip (grid 1x1)")
         dt = jnp.dtype(self.cfg["dtype"])
         self._make = self._local_maker(jax, jnp, dt)
-        solve = solve_fn(st, self.cfg, options(st, self.cfg))
+        solve = solve_fn(st, self.cfg, options(st, self.cfg), self.cell.root)
         # one program, as a user jits the call (the driver called eagerly
         # re-lowers its pieces on every call)
         jsolve = jax.jit(solve)
@@ -146,6 +141,7 @@ class Run:
             "ops_per_solve": w.ops(self.n, self.nrhs),
             "bytes_per_solve": w.bytes_moved(self.n, self.nrhs, item),
             "chips": self.p * self.q,
+            "config": self.cfg,
         }
 
     def check(self):

@@ -9,10 +9,19 @@ Every part lives in a file of its own under ``benchmark/``:
   general driver reading it (``"driver"``);
 - ``drivers/<driver>.py``: one general traffic driver;
 - ``layer_metrics/<metric>.py``: one per-layer metric reader, ``read(run)``;
-- ``work/<routine>.py``: the model operations and bytes of one routine.
+- ``routines/<routine>.py``: one routine's public driver call on global
+  device arrays, ``call(st, A, B, nb, opts)``, its control,
+  ``control(config, A, B)``, and how the benchmark's tests check that
+  control on the CPU: ``CONTROL_N``, the sizes at which it runs there
+  and fails the config's limits, or, where none does, ``CONTROL_N = ()``
+  and ``CONTROL_CHIP``, its readings on the chip at the cell's size, one
+  dict of numbers per seed (the routine the config names);
+- ``work/<routine>.py``: the model operations and bytes of one routine,
+  part of the yardstick: kept apart from the routine's file, which calls
+  the program, so that the readers that count work import no program.
 
-A later PR adds a cell, a mix or a metric by adding files and entries;
-nothing here lists them.
+A later PR adds a cell, a mix, a metric or a routine by adding files and
+entries; nothing here lists them.
 """
 
 from __future__ import annotations
@@ -66,6 +75,12 @@ def reader(metric: str, root: str = ROOT):
         f"bench_metric_{_safe(metric)}")
 
 
+def routine(name: str, root: str = ROOT):
+    return _module(
+        os.path.join(root, "benchmark", "routines", f"{name}.py"),
+        f"bench_routine_{_safe(name)}")
+
+
 def work(routine: str, root: str = ROOT):
     return _module(os.path.join(root, "benchmark", "work", f"{routine}.py"),
                    f"bench_work_{_safe(routine)}")
@@ -109,6 +124,9 @@ class Cell:
     def driver(self):
         return driver(self.traffic["driver"], self.root)
 
+    def routine(self):
+        return routine(self.config["routine"], self.root)
+
     def work(self):
         return work(self.config["routine"], self.root)
 
@@ -122,6 +140,7 @@ def parts(root: str = ROOT) -> dict:
         c = Cell(w["name"], root)
         out["cells"][w["name"]] = {
             "driver": c.driver().__name__,
+            "routine": c.routine().__name__,
             "end_to_end": [m["name"] for m in c.end_to_end],
             "per_layer": [m["name"] for m in c.per_layer],
         }

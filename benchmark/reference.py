@@ -1,5 +1,6 @@
-"""The plain reference, the comparison that decides ``correct``, and the
-control (the reference computed one precision below the configuration).
+"""The plain reference and the comparison that decides ``correct``.  The
+control (the reference computed one precision below the configuration)
+is each routine's own, in ``routines/<routine>.py``.
 
 Nothing here imports the program.  The reference rebuilds every operand
 from the seed (``gen``), solves it in float64 with numpy's LAPACK, and
@@ -88,22 +89,3 @@ def judge(worst: dict, lim: dict) -> list:
     """[(name, value, limit, ok)] in a fixed order."""
     return [(k, worst[k], lim[k], bool(worst[k] <= lim[k])) for k in lim]
 
-
-# ---------------------------------------------------------------------------
-# the control: the reference one precision below the configuration
-# ---------------------------------------------------------------------------
-
-
-def control_solve(config: dict, A, B):
-    """The control's X for one operand pair (host float64 arrays in, a
-    device array out): XLA's LU with partial pivoting in the control's
-    dtype, every product at its precision."""
-    import jax
-    import jax.numpy as jnp
-
-    c = config["control"]
-    if config["routine"] != "gesv" or c["precision"] != "highest":
-        raise ValueError("the control is gesv at 'highest' only")
-    dt = jnp.dtype(c["dtype"])
-    with jax.default_matmul_precision("highest"):
-        return jnp.linalg.solve(jnp.asarray(A, dt), jnp.asarray(B, dt))

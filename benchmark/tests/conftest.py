@@ -28,26 +28,53 @@ jax.config.update("jax_enable_x64", True)
 
 import pytest  # noqa: E402
 
-#: tiny sizes per config (n, nb)
-TINY = {"hpl": (256, 64)}
+import harness  # noqa: E402
+
+#: the CPU rehearsal's cut of every config: n and nb at most these; nrhs,
+#: dtype and grid as the config states them
+CUT_N, CUT_NB = 256, 64
 
 
-def make_tiny(dst: str) -> str:
-    """A copy of BENCHMARK.json and benchmark/ with every cell at a tiny
-    size and a roof for the CPU, so the whole run path works here."""
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), dst)
-    shutil.copytree(BENCH, os.path.join(dst, "benchmark"),
+def make_tiny(dst: str, root: str = ROOT) -> str:
+    """A copy of ``root``'s BENCHMARK.json and benchmark/ with every config
+    cut to a tiny size and a roof for the CPU, so the whole run path works
+    here."""
+    shutil.copy(os.path.join(root, "BENCHMARK.json"), dst)
+    shutil.copytree(os.path.join(root, "benchmark"),
+                    os.path.join(dst, "benchmark"),
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    for name, (n, nb) in TINY.items():
-        p = os.path.join(dst, "benchmark", "configs", f"{name}.json")
-        c = json.load(open(p))
-        c.update(n=n, nb=nb)
-        json.dump(c, open(p, "w"))
+    for entry in harness.benchmark(dst)["configs"]:
+        p = os.path.join(dst, entry["file"])
+        c = harness.load_json(p)
+        c.update(n=min(int(c["n"]), CUT_N), nb=min(int(c["nb"]), CUT_NB))
+        with open(p, "w") as f:
+            json.dump(c, f)
     p = os.path.join(dst, "benchmark", "roofs.json")
-    r = json.load(open(p))
+    r = harness.load_json(p)
     r["kinds"]["cpu"] = {"flops_per_s": 1e12, "bytes_per_s": 1e11}
-    json.dump(r, open(p, "w"))
+    with open(p, "w") as f:
+        json.dump(r, f)
     return dst
+
+
+def library_cells(root: str = ROOT) -> list:
+    """Every cell whose traffic runs whole library solves: each gets the
+    faults of test_faults, planted around its routine's ``call``."""
+    return [w["name"] for w in harness.benchmark(root)["workloads"]
+            if harness.Cell(w["name"], root).traffic["driver"]
+            == "library_solve"]
+
+
+def control_cases(root: str = ROOT) -> list:
+    """(config, n) of the control test: every config of BENCHMARK.json,
+    at each size its routine's ``CONTROL_N`` names, or once with n None
+    where it names none and the chip readings stand in."""
+    out = []
+    for entry in harness.benchmark(root)["configs"]:
+        c = harness.load_json(os.path.join(root, entry["file"]))
+        ns = harness.routine(c["routine"], root).CONTROL_N
+        out += [(entry["name"], n) for n in ns] or [(entry["name"], None)]
+    return out
 
 
 @pytest.fixture(scope="session")

@@ -6,57 +6,86 @@ the cell can have."""
 import numpy as np
 import pytest
 
-import reference
 import harness
-from conftest import run_cell
+import reference
+from conftest import control_cases, library_cells, run_cell
+
+CONTROLS = control_cases()
 
 
-@pytest.mark.parametrize("n", [512, 1024])
-def test_control_is_not_correct(tiny_root, n):
-    """The control at sizes a test run holds (the chip readings at the
-    cell's own size are in PERF.md)."""
-    cfg = harness.load_json(f"{tiny_root}/benchmark/configs/hpl.json")
+def control_fails(root, config, n):
+    """Every reading of ``config``'s control fails one of its limits."""
+    entry = {c["name"]: c for c in harness.benchmark(root)["configs"]}
+    cfg = harness.load_json(f"{root}/{entry[config]['file']}")
+    mod = harness.routine(cfg["routine"], root)
     lim = reference.limits(cfg)
-    fails = 0
-    for seed in (11, 12, 13):
-        A, B = reference.host_operands(cfg, n, cfg["nrhs"], seed, 0)
-        X = np.asarray(reference.control_solve(cfg, A, B), np.float64)
-        got = reference.numbers(cfg, A, X, B, reference.solve(A, B))
-        fails += any(not got[m] <= lim[m] for m in lim)
-    assert fails == 3
+    if n is None:
+        readings = list(mod.CONTROL_CHIP)
+        assert len(readings) >= 3
+    else:
+        readings = []
+        for seed in (11, 12, 13):
+            A, B = reference.host_operands(cfg, n, cfg["nrhs"], seed, 0)
+            X = np.asarray(mod.control(cfg, A, B), np.float64)
+            readings.append(
+                reference.numbers(cfg, A, X, B, reference.solve(A, B)))
+    for got in readings:
+        assert any(not got[m] <= lim[m] for m in got if m in lim), got
+
+
+@pytest.mark.parametrize("config,n", CONTROLS,
+                         ids=[f"{c}-{n or 'chip'}" for c, n in CONTROLS])
+def test_control_is_not_correct(config, n):
+    """The control of every config, as its routine's file says to check
+    it: run on the CPU at each of its ``CONTROL_N``, or, where it has
+    none, its ``CONTROL_CHIP`` readings at the cell's own size."""
+    control_fails(harness.ROOT, config, n)
 
 
 # -- faults planted in the program, the rest of a run as it stands -------
 
 
-def _unchanged(orig):
-    def fake(A, B, opts=None):
-        out = orig(A, B, opts)
-        return (B,) + tuple(out[1:])
+def _unchanged(call):
+    def fake(st, A, B, nb, opts):
+        return B
     return fake
 
 
-def _altered(orig):
-    def fake(A, B, opts=None):
-        out = orig(A, B, opts)
-        X = out[0]
-        data = X.data.at[(0,) * X.data.ndim].multiply(1 + 1e-3)
-        return (X._with(data=data),) + tuple(out[1:])
+def _altered(call):
+    def fake(st, A, B, nb, opts):
+        X = call(st, A, B, nb, opts)
+        return X.at[(0,) * X.ndim].multiply(1 + 1e-3)
     return fake
 
 
-FAULTS = [
-    ("hpl.f64.n8192", "gesv", _unchanged),
-    ("hpl.f64.n8192", "gesv", _altered),
-]
+FAULT_KINDS = (_unchanged, _altered)
 
 
-@pytest.mark.parametrize("cell,routine,fault", FAULTS,
-                         ids=[f"{c}-{f.__name__}" for c, _r, f in FAULTS])
-def test_library_fault_is_not_correct(tiny_root, capsys, monkeypatch, cell,
-                                      routine, fault):
-    import slate_tpu as st
+def plant(monkeypatch, fault):
+    """Every routine file the run loads gets ``fault`` around its
+    ``call``: the fault follows ``routines/<routine>.py``."""
+    real = harness.routine
 
-    monkeypatch.setattr(st, routine, fault(getattr(st, routine)))
-    rc, res = run_cell(tiny_root, cell, seconds=0.5, capsys=capsys)
+    def routine(name, root=harness.ROOT):
+        mod = real(name, root)
+        mod.call = fault(mod.call)
+        return mod
+
+    monkeypatch.setattr(harness, "routine", routine)
+
+
+def fault_fails(root, cell, fault, monkeypatch, capsys):
+    plant(monkeypatch, fault)
+    rc, res = run_cell(root, cell, seconds=0.5, capsys=capsys)
+    monkeypatch.undo()
     assert rc == 0 and res["correct"] is False and res["failed"] > 0
+
+
+PLANTED = [(c, f) for c in library_cells() for f in FAULT_KINDS]
+
+
+@pytest.mark.parametrize("cell,fault", PLANTED,
+                         ids=[f"{c}-{f.__name__}" for c, f in PLANTED])
+def test_library_fault_is_not_correct(tiny_root, capsys, monkeypatch, cell,
+                                      fault):
+    fault_fails(tiny_root, cell, fault, monkeypatch, capsys)

@@ -37,7 +37,7 @@ def test_refuses_cpu_and_prints_nothing():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-         "--workload", "hpl.f64.n8192", "--seed", "1", "--seconds", "1",
+         "--workload", CELLS[0], "--seed", "1", "--seconds", "1",
          "--trace", "0"], env=env, capture_output=True, text=True,
         timeout=120)
     assert p.returncode != 0
@@ -61,7 +61,7 @@ def test_refuses_directory_without_benchmark(tmp_path):
             (tmp_path / "benchmark" / f).write_text(src.read())
     p = subprocess.run(
         [sys.executable, str(tmp_path / "benchmark" / "run.py"),
-         "--workload", "hpl.f64.n8192", "--seed", "1", "--seconds", "1",
+         "--workload", CELLS[0], "--seed", "1", "--seconds", "1",
          "--trace", "0"], env=env, capture_output=True, text=True,
         timeout=120, cwd=tmp_path)
     assert p.returncode != 0 and p.stdout.strip() == ""
