@@ -124,3 +124,14 @@ def hdot(a, b, **kw):
         part = _hdot_raw(a[:, s : s + _KCHUNK], b[s : s + _KCHUNK, :], **kw)
         acc = part if acc is None else acc + part
     return acc
+
+
+def short_dot(a, b):
+    """a @ b for a short contraction (a panel's strip width, a few
+    dozen).  Where f64 is emulated, one fused elementwise product and
+    sum: the emulated dot costs about as much per output for any
+    contraction up to a few hundred.  Elsewhere ``hdot`` (the MXU for
+    f32/c64, a native dot for real f64)."""
+    if emulated_f64(a.dtype):
+        return (a[:, :, None] * b[None, :, :]).sum(1)
+    return hdot(a, b)

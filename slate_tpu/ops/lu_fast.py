@@ -9,13 +9,11 @@ pivot broadcast, row exchange, trsm row, trailing gemm) as a
 three-level TPU schedule, the LU analogue of
 ops/chol_kernels.py:
 
-* micro level (``_lu_panel_strips``): one fori_loop over ib-wide column
-  strips of an (m, nb) panel.  Per column: VPU argmax pivot search,
-  two-row swap, rank-1 update restricted to the strip; per strip: a
-  unit-lower strip inverse by nilpotent squaring ((I+N)^-1 =
-  (I-N)(I+N^2)(I+N^4)... exact because N^ib = 0) and one rank-ib MXU
-  update of the rest of the panel.  This bounds the bandwidth-bound
-  per-column traffic at O(m*ib) instead of O(m*nb).
+* micro level (``lu_kernels._lu_panel_strips``, shared with
+  ``blocked_getrf``): partial-pivot LU of an (m, nb) panel in ib-wide
+  column strips, per-column work confined to the strip, one rank-ib
+  update of the panel per strip (``precision.short_dot``: the MXU for
+  f32/c64, elementwise for emulated f64).
 * sub-panel level (``_block_lu``): one fori_loop over the nb-wide
   panels of an (m, NB) coarse block; the active region is rolled to the
   top so every iteration keeps one static shape.  Row exchanges are one
@@ -43,109 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..internal.precision import hdot as _dot
-
-
-def _unit_lower_inv(L: jnp.ndarray) -> jnp.ndarray:
-    """Exact inverse of a unit-lower (b, b) matrix by nilpotent squaring:
-    (I + N)^-1 = (I - N)(I + N^2)(I + N^4)...  — log2(b) small matmuls,
-    no triangular-solve lowering."""
-    b = L.shape[0]
-    eye = jnp.eye(b, dtype=L.dtype)
-    N = jnp.tril(L, -1)
-    inv = eye - N
-    P = N
-    k = 2
-    while k < b:
-        P = _dot(P, P)  # N^(2^j)
-        inv = _dot(inv, eye + P)
-        k *= 2
-    return inv
-
-
-def _lu_panel_strips(
-    P: jnp.ndarray, act, ib: int = 32
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Partial-pivot LU of an (m, w) panel; only rows < act are eligible
-    pivots (the rest is padding).  w must be a multiple of ib.
-
-    No row is moved during elimination: pivoting is tracked with an
-    eligibility mask (the Schur update is row-order independent), so the
-    per-column work touches only the (m, ib) strip — the swap-based
-    variant's four full-panel row updates per column dominated its
-    runtime.  One ordering gather at the end produces the same row
-    order (and net forward permutation) as LAPACK's swap sequence.
-
-    Returns (P, perm): P holds unit-lower L below the diagonal and U
-    on/above for the w eliminated columns, rows in LAPACK pivot order;
-    P rows correspond to input rows perm."""
-    m, w = P.shape
-    rows = jnp.arange(m)
-    colsw = jnp.arange(w)
-    ibr = jnp.arange(ib)
-
-    def strip(s, carry):
-        P, unpiv, pivrows = carry
-        j0 = s * ib
-        S = lax.dynamic_slice(P, (0, j0), (m, ib))
-        for c in range(ib):
-            colc = S[:, c]
-            mag = jnp.where(unpiv, jnp.abs(colc), -jnp.inf)
-            piv = jnp.argmax(mag)
-            pv = colc[piv]
-            safe = jnp.where(pv == 0, jnp.ones_like(pv), pv)
-            elig = unpiv & (rows != piv) & (pv != 0)
-            l = jnp.where(elig, colc / safe, jnp.zeros((), P.dtype))
-            # pivoted rows keep their U entries; the pivot row keeps pv
-            S = S.at[:, c].set(jnp.where(unpiv & (rows != piv), l, colc))
-            unpiv = unpiv.at[piv].set(False)
-            pivrows = pivrows.at[j0 + c].set(piv.astype(jnp.int32))
-            if c + 1 < ib:
-                # rank-1 on the strip's remaining columns only (c is a
-                # Python int, so the tail slice is static — halves the
-                # bandwidth-bound micro traffic vs updating all of S)
-                tail = S[:, c + 1 :]
-                urow = tail[piv]
-                S = S.at[:, c + 1 :].set(tail - jnp.outer(l, urow))
-        P = lax.dynamic_update_slice(P, S, (0, j0))
-        # rank-ib update of the rest of the panel: gather the strip's
-        # pivot rows, exact unit-lower inverse by nilpotent squaring,
-        # one MXU gemm.  Lss[j, c] for j > c is the column-c multiplier
-        # of pivot row p_j (recorded in S before p_j was pivoted).
-        stripiv = lax.dynamic_slice(pivrows, (j0,), (ib,))
-        Srows = P[stripiv]  # (ib, w)
-        D = lax.dynamic_slice(Srows, (0, j0), (ib, ib))
-        Linv = _unit_lower_inv(D)
-        U12 = _dot(Linv, Srows)
-        cmask = (colsw >= j0 + ib)[None, :]
-        P = P.at[stripiv].set(jnp.where(cmask, U12, Srows))
-        L21 = jnp.where(unpiv[:, None], S, jnp.zeros((), P.dtype))
-        return (
-            P - jnp.where(cmask, _dot(L21, U12), jnp.zeros((), P.dtype)),
-            unpiv,
-            pivrows,
-        )
-
-    unpiv0 = rows < act
-    pivrows0 = jnp.zeros((w,), jnp.int32)
-    P, unpiv, pivrows = lax.fori_loop(
-        0, w // ib, strip, (P, unpiv0, pivrows0)
-    )
-
-    # Reconstruct LAPACK's row order: replay the swap sequence
-    # (column j swaps positions j <-> current position of pivrows[j])
-    # on an index vector.  O(w) scalar steps — tiny next to the strips.
-    def replay(j, carry):
-        perm, pos = carry
-        p = pos[pivrows[j]]
-        rj = perm[j]
-        rp = perm[p]
-        perm = perm.at[j].set(rp).at[p].set(rj)
-        pos = pos.at[rp].set(j).at[rj].set(p)
-        return perm, pos
-
-    perm0 = jnp.arange(m, dtype=jnp.int32)
-    perm, _ = lax.fori_loop(0, w, replay, (perm0, perm0))
-    return P[perm], perm
+from .lu_kernels import _lu_panel_strips
 
 
 def _block_lu(
@@ -208,8 +104,9 @@ def blocked_getrf_fast(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Blocked right-looking LU with partial pivoting of a square padded
     array (n a multiple of nb).  Returns (LU, perm): LU = (L\\U) of
-    G[perm].  Same contract as lu_kernels.blocked_getrf; ~15x faster at
-    n >= 4096 on the chip (exact-shape trailing gemms at MXU rate).
+    G[perm].  Same contract as lu_kernels.blocked_getrf, with exact-shape
+    trailing gemms; its speed on the chip is not measured (no benchmark
+    cell runs it).
     """
     n = G.shape[0]
     assert n % nb == 0, f"blocked_getrf_fast: n={n} not a multiple of nb={nb}"

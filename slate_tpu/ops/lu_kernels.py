@@ -9,12 +9,17 @@ for the f64/c128 path here.  Everything is static-shape fori_loop code:
   the analogue of the reference's threaded panel kernel
   (Tile_getrf.hh:164-452) with the per-column argmax done by lax.argmax
   over the whole gathered panel instead of a thread/MPI reduction tree.
+* ``_lu_panel_strips`` — the same panel factor blocked in ib = 32
+  column strips (LAPACK dgetrf2): each column's pivot search and rank-1
+  touch only its strip, one rank-ib update per strip, rows moved once
+  at the end.  Also lu_fast's micro level.
 * ``blocked_getrf`` — right-looking blocked LU over the padded global
   array: per step the panel is rolled to the top (static shapes), factored
-  redundantly, row swaps applied as one gather, then one triangular solve
-  + one matmul for the trailing update (getrf.cc:183-214's permuteRows +
-  trsm + gemm fused into three XLA ops).  The steps run in one loop at
-  the full padded shape below 2048, else in at most 4 loops at the exact
+  redundantly (the strip panel from nb = 64, else ``panel_lu``), row
+  swaps applied as one gather, then one triangular solve + one matmul
+  for the trailing update (getrf.cc:183-214's permuteRows + trsm + gemm
+  fused into three XLA ops).  The steps run in one loop at the full
+  padded shape below 2048, else in at most 4 loops at the exact
   trailing shape where each starts.
 
 Used by drivers/lu.py whenever the platform lacks a vendor LU for the
@@ -29,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..internal.precision import hdot as _dot
+from ..internal.precision import hdot as _dot, short_dot
 from .chol_kernels import (
     RECURSIVE_MIN_N,
     _lat_height,
@@ -87,6 +92,98 @@ def panel_lu(
     return lu, perm
 
 
+# column-strip width of the blocked panel (LAPACK dgetrf2 / MAGMA's ib)
+PANEL_IB = 32
+
+
+def _lu_panel_strips(
+    P: jnp.ndarray, act, ib: int = PANEL_IB
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Partial-pivot LU of an (m, w) panel in ib-wide column strips;
+    only rows < act are eligible pivots (the rest is padding).  w must
+    be a multiple of ib.  LAPACK dgetrf2's blocking.
+
+    The panel is factored transposed, Q = P^T (w, m), so that a column
+    is a lane-dense row.  No row moves during elimination: a mask
+    tracks the pivoted rows (the Schur update is row-order
+    independent), and LAPACK's swap sequence is replayed on the index
+    vector perm as each pivot is chosen.  Per column the pivot search,
+    the scaling and the rank-1 update touch only the (ib, m) strip, and
+    the new pivot row's entries right of the strip become its row of U
+    by one step of forward substitution (Crout).  Per strip one rank-ib
+    update (``short_dot``) brings the other rows up to date.
+
+    Returns (P, perm): P holds unit-lower L below the diagonal and U
+    on/above for the w eliminated columns, rows in LAPACK pivot order;
+    P rows correspond to input rows perm.  Pivot choice is LAPACK's
+    (largest magnitude) except on exact ties, which go to the lowest
+    input row."""
+    m, w = P.shape
+    lanes = jnp.arange(m)
+    ibr = jnp.arange(ib)
+    right_of = jnp.arange(w)
+    zero = jnp.zeros((), P.dtype)
+
+    def strip(s, carry):
+        Q, unpiv, perm = carry
+        j0 = s * ib
+        S = lax.dynamic_slice(Q, (j0, 0), (ib, m))
+
+        def column(c, carry):
+            S, unpiv, perm, U = carry
+            col = lax.dynamic_index_in_dim(S, c, 0, keepdims=False)
+            piv = jnp.argmax(jnp.where(unpiv, jnp.abs(col), -jnp.inf))
+            piv = piv.astype(jnp.int32)
+            pv = col[piv]
+            safe = jnp.where(pv == 0, jnp.ones_like(pv), pv)
+            below = unpiv & (lanes != piv)
+            l = jnp.where(below & (pv != 0), col / safe, zero)
+            unpiv = unpiv.at[piv].set(False)
+            # LAPACK's swap of position j and piv's position
+            j = j0 + c
+            p = jnp.argmax(perm == piv)
+            perm = perm.at[p].set(perm[j]).at[j].set(piv)
+            # the pivot row's U: its entries as the strip began less
+            # its multipliers (S[:c, piv]) times the earlier U rows
+            lp = jnp.where(ibr < c, S[:, piv], zero)
+            U = lax.dynamic_update_index_in_dim(
+                U, Q[:, piv] - (U * lp[None, :]).sum(1), c, 1
+            )
+            # pivoted rows keep their U entries, the pivot row its pv
+            S = lax.dynamic_update_index_in_dim(
+                S, jnp.where(below, l, col), c, 0
+            )
+            urow = jnp.where(ibr > c, S[:, piv], zero)
+            return S - jnp.outer(urow, l), unpiv, perm, U
+
+        U = jnp.zeros((w, ib), P.dtype)
+        S, unpiv, perm, U = lax.fori_loop(
+            0, ib, column, (S, unpiv, perm, U)
+        )
+        Q = lax.dynamic_update_slice(Q, S, (j0, 0))
+        # rank-ib update of the unpivoted rows right of the strip, then
+        # the pivot rows' U there
+        right = (right_of >= j0 + ib)[:, None]
+        U = jnp.where(right, U, zero)
+        Q = Q - short_dot(U, jnp.where(unpiv[None, :], S, zero))
+        piv_rows = lax.dynamic_slice(perm, (j0,), (ib,))
+        Q = Q.at[:, piv_rows].set(jnp.where(right, U, Q[:, piv_rows]))
+        return Q, unpiv, perm
+
+    perm = jnp.arange(m, dtype=jnp.int32)
+    Q, _, perm = lax.fori_loop(
+        0, w // ib, strip, (jnp.transpose(P), lanes < act, perm)
+    )
+    return Q.T[perm], perm
+
+
+def _strip_panel(nb: int) -> bool:
+    """Whether ``_getrf_steps`` factors its (M, nb) panels by
+    ``_lu_panel_strips``: at least two whole strips, else ``panel_lu``.
+    Shared with ``getrf_schedule_flops``."""
+    return nb % PANEL_IB == 0 and nb >= 2 * PANEL_IB
+
+
 # the segmented blocked_getrf splits its steps into at most this many
 # exact-shape loops (one compiled loop body each)
 _FLAT_SEGMENTS = 4
@@ -110,7 +207,8 @@ def _getrf_steps(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The first ``steps`` nb-wide steps of the right-looking LU of an
     (M, N) array, in one fori_loop at the full array shape: every step
-    factors its panel, swaps rows across the whole array and updates
+    factors its panel (``_lu_panel_strips`` where ``_strip_panel(nb)``,
+    else ``panel_lu``), swaps rows across the whole array and updates
     the whole trailing part (finished rows and columns masked to zero).
     Returns (G, perm): G holds L\\U in its first steps*nb columns and
     rows and the updated trailing block, rows in the order perm."""
@@ -127,7 +225,10 @@ def _getrf_steps(
             active_len = M - k * nb
             colr = jnp.where((rows < active_len)[:, None], colr,
                              jnp.zeros_like(colr))
-            lu_pan, piv = panel_lu(colr)
+            if _strip_panel(nb):
+                lu_pan, piv = _lu_panel_strips(colr, active_len)
+            else:
+                lu_pan, piv = panel_lu(colr)
             # step permutation in global row space (identity above the
             # panel)
             act = rows - k * nb
@@ -169,6 +270,11 @@ def blocked_getrf(
     spliced to 1 (layout.eye_splice semantics).  Returns (LU, perm) with
     perm the net forward row permutation: LU = (L\\U) of Gp[perm].
     Reference: src/getrf.cc:85-214.
+
+    Each step's (M, nb) panel is factored by the strip panel
+    ``_lu_panel_strips`` (ib = ``PANEL_IB``) where nb is a multiple of
+    ib and at least two strips wide, else by ``panel_lu``; the pivots
+    are the same on inputs without magnitude ties.
 
     Below ``RECURSIVE_MIN_N`` all min(Mp, Np)/nb steps run in one loop
     at the full padded shape (one compiled body).  Above it the steps
@@ -483,6 +589,13 @@ def getrf_schedule_flops(
         # whole (M, b) panel
         return 2.0 * M * b * min(M, b), {(panel_unit, M, b)}
 
+    def strip_panel_flops(M, b, ib=PANEL_IB):
+        # _lu_panel_strips, per strip: each column's rank-1 on the
+        # (M, ib) strip and its pivot row's U across the panel (b x ib),
+        # and one rank-ib update of the whole (M, b) panel
+        per_strip = 2.0 * M * ib * ib + 2.0 * b * ib * ib + 2.0 * M * ib * b
+        return (b // ib) * per_strip, {("lu_panel_strips", M, b, ib)}
+
     if schedule == "vendor":
         # the vendor kernel still runs on the PADDED array
         return {"model": model,
@@ -494,7 +607,8 @@ def getrf_schedule_flops(
         ex, units = 0.0, set()
         for k0, steps in _flat_segments(m, n, nb):
             ms, ns = m - k0, n - k0
-            fp, up = panel_flops(ms, nb)
+            fp, up = (strip_panel_flops if _strip_panel(nb)
+                      else panel_flops)(ms, nb)
             ex += steps * (fp + float(ns) * nb * nb + 2.0 * ms * ns * nb)
             units |= up | {("trsm", nb, ns), ("gemm", ms, nb, ns)}
         return {"model": model, "exec": ex, "units": units}
@@ -509,10 +623,12 @@ def getrf_schedule_flops(
         while k0 < n:
             W = min(NB, n - k0)
             mk = m - k0
-            # _block_lu(mk, W): strips + in-block trsm/gemm, all masked
-            # to the full (mk, W) block per panel
-            ex += 2.0 * mk * nbf * W + 2.0 * nbf * W * W + 2.0 * mk * W * W
-            units |= {("lu_block", mk, W)}
+            # _block_lu(mk, W): per nbf-wide panel the strip panel at
+            # the full block height, then in-block trsm/gemm masked to
+            # the full (mk, W) block
+            fp, up = strip_panel_flops(mk, nbf)
+            ex += (W // nbf) * fp + 2.0 * nbf * W * W + 2.0 * mk * W * W
+            units |= up | {("lu_block", mk, W)}
             rest = n - k0 - W
             if rest > 0:
                 ex += W**3 / 2.0 + 2.0 * W * W * rest

@@ -208,7 +208,7 @@ def test_flops_ratio_acceptance_n2048():
     chf = chol_schedule_flops(2048, 512, "flat_fori")
     luf = getrf_schedule_flops(2048, 2048, 512, "flat")
     assert chf["exec"] / chf["model"] > 2.0
-    assert luf["exec"] / luf["model"] == pytest.approx(2.109375)
+    assert luf["exec"] / luf["model"] == pytest.approx(2.150390625)
     assert lu["exec"] < luf["exec"]
 
 
@@ -241,12 +241,51 @@ def test_recursive_beats_flat_at_scale():
 
 def test_flat_getrf_segments_n8192():
     """The flat getrf at n=8192, nb=512 runs four exact-shape loops of
-    four steps (8192, 6144, 4096, 2048): 1.58x the model FLOPs, where
-    one full-shape loop ran 3.28x."""
+    four steps (8192, 6144, 4096, 2048): 1.59x the model FLOPs with the
+    strip panel, where one full-shape loop ran 3.28x."""
     lu = getrf_schedule_flops(8192, 8192, 512, "flat")
-    assert lu["exec"] / lu["model"] == pytest.approx(1.58203125)
+    assert lu["exec"] / lu["model"] == pytest.approx(1.590087890625)
     gemms = sorted(u for u in lu["units"] if u[0] == "gemm")
     assert gemms == [("gemm", s, 512, s) for s in (2048, 4096, 6144, 8192)]
+
+
+def test_flat_getrf_strip_panel_n8192():
+    """At nb=512 every segment's panel is the strip panel (ib=32, 16
+    strips, each updating the whole 512-wide panel): one
+    ("lu_panel_strips", M, 512, 32) unit per height, and its FLOPs as
+    run; below two strips the unit is panel_lu's."""
+    heights = (8192, 6144, 4096, 2048)
+    lu = getrf_schedule_flops(8192, 8192, 512, "flat")
+    panels = sorted(u for u in lu["units"] if u[0].startswith("lu_panel"))
+    assert panels == [("lu_panel_strips", s, 512, 32) for s in heights[::-1]]
+    want = 4 * sum(_strip_panel(s, 512) + s * 512.0 * 512
+                   + 2.0 * s * s * 512 for s in heights)
+    assert lu["exec"] == pytest.approx(want, rel=1e-12)
+    small = getrf_schedule_flops(64, 64, 16, "flat")
+    assert ("lu_panel", 64, 16) in small["units"]
+
+
+def _strip_panel(M, w, ib=32):
+    # per strip: the column rank-1s on the (M, ib) strip, each pivot
+    # row's U across the panel, one rank-ib update of the panel
+    return (w // ib) * (2.0 * M * ib * ib + 2.0 * w * ib * ib
+                        + 2.0 * M * ib * w)
+
+
+def test_flat_fast_getrf_strip_panel_n8192():
+    """lu_fast at n=8192 (nb=512, four 2048-wide coarse blocks) factors
+    each block's four 512-wide panels by the shared strip panel at the
+    block's full height: its unit and FLOPs are counted as run."""
+    lu = getrf_schedule_flops(8192, 8192, 512, "flat_fast")
+    heights = (8192, 6144, 4096, 2048)
+    panels = sorted(u for u in lu["units"] if u[0].startswith("lu_panel"))
+    assert panels == [("lu_panel_strips", s, 512, 32) for s in heights[::-1]]
+    W = 2048.0
+    blocks = sum(4 * _strip_panel(s, 512) + 2.0 * 512 * W * W
+                 + 2.0 * s * W * W for s in heights)
+    rest = sum(W ** 3 / 2 + 2.0 * W * W * r + 2.0 * (s - W) * W * r
+               for s, r in zip(heights, (6144, 4096, 2048)))
+    assert lu["exec"] == pytest.approx(blocks + rest, rel=1e-12)
 
 
 # ---------------------------------------------------------------------------

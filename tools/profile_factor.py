@@ -67,7 +67,8 @@ def main():
 
     # -- totals --------------------------------------------------------
     from slate_tpu.ops.chol_kernels import blocked_potrf, chol_unblocked
-    from slate_tpu.ops.lu_fast import blocked_getrf_fast, _lu_panel_strips
+    from slate_tpu.ops.lu_fast import blocked_getrf_fast
+    from slate_tpu.ops.lu_kernels import _lu_panel_strips
     from slate_tpu.ops.qr_fast import geqrf_fast, _qr_panel_strips
 
     S = A @ A.T + n * jnp.eye(n, dtype=jnp.float64)
